@@ -155,8 +155,8 @@ def bench_kernel(quick: bool):
         static_speedup = static_rank1_us / min(static candidate walls)
 
     is >= 1.0 by construction (same-run, same-host walls with the baseline
-    in the pool) — the contract behind regress.py's 1.0 floors.  NOTE: this
-    container runs the kernels in ``interpret=True`` on CPU, where
+    in the pool) — the contract behind regress.py's 1.0 floors.  NOTE: on the
+    CPU the kernels run in the Pallas interpreter, where
     per-iteration dispatch cost is not the TPU's — the trip count (``bk``
     rank-1 steps vs ``bk/ks`` slab steps) stays the architecture-relevant
     number, and the autotuner is exactly the mechanism that picks the right

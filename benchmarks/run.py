@@ -19,12 +19,15 @@ import argparse
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (adaptive_table, app_table, audit_report, chaos_table,
                component_table, fleet_table, hw_table, perf_table, regress,
                roofline_table, serving_table)
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", help="small fast subset")
     ap.add_argument("--full", action="store_true", help="all multipliers + ALL parts")

@@ -80,6 +80,12 @@ class AxMult:
 # helpers
 # ---------------------------------------------------------------------------
 
+def _at_least_one(xu):
+    """max(xu, 1) for uint32 lanes, written as a select: Mosaic (the TPU
+    kernel compiler) has no unsigned max."""
+    return jnp.where(xu == 0, jnp.uint32(1), xu)
+
+
 def _mask(m: int) -> int:
     return (1 << m) - 1
 
@@ -203,7 +209,7 @@ def mitchell(bits: int, ta: int = 0, tb: int = 0, signed: bool = False) -> AxMul
 
     def _logfrac(xu, t):
         # position of the MSB (x > 0 guaranteed by caller's select)
-        k = (jnp.uint32(31) - jax.lax.clz(jnp.maximum(xu, jnp.uint32(1)))).astype(jnp.uint32)
+        k = (jnp.uint32(31) - jax.lax.clz(_at_least_one(xu))).astype(jnp.uint32)
         # fraction f in F-bit fixed point: ((x - 2^k) << F) >> k
         frac = ((xu - (jnp.uint32(1) << k)) << jnp.uint32(F)) >> k
         if t > 0:
@@ -240,7 +246,7 @@ def drum(bits: int, ka: int, kb: int, signed: bool = False) -> AxMult:
     ``ka != kb``."""
 
     def _segment(xu, k):
-        msb = (jnp.uint32(31) - jax.lax.clz(jnp.maximum(xu, jnp.uint32(1)))).astype(jnp.int32)
+        msb = (jnp.uint32(31) - jax.lax.clz(_at_least_one(xu))).astype(jnp.int32)
         sh = jnp.maximum(msb - (k - 1), 0).astype(jnp.uint32)
         seg = (xu >> sh)
         # unbias: set LSB of the segment when we actually truncated

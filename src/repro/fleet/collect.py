@@ -32,22 +32,13 @@ from repro.runtime.telemetry import (
     tile_summary,
 )
 
-try:  # jax >= 0.5 re-exports shard_map at the top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 __all__ = [
-    "shard_map",
     "batch_axis_names",
     "aggregate_records",
     "shard_decode_specs",
     "token_step_specs",
     "make_sharded_summarizer",
 ]
-
-shard_map = _shard_map
-
 
 def batch_axis_names(mesh: Mesh) -> Tuple[str, ...]:
     """The mesh axes the batch dimension shards over — mirrors the 'batch'
@@ -207,7 +198,7 @@ def make_sharded_summarizer(mult_name: str, mesh: Mesh, target: str = "stream",
                 tile_key(target): {k: v[None] for k, v in trec.items()}}
         return aggregate_records(recs, axes)
 
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(P(axes), P(axes), P()), out_specs=P(),
-                  check_rep=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(axes), P(axes), P()), out_specs=P(),
+                      check_vma=False)
     return jax.jit(f)

@@ -15,15 +15,18 @@ TPU adaptation notes
 * The K reduction runs as the innermost grid dimension with output-block
   revisiting (init at k==0, accumulate after), the standard Pallas matmul
   reduction pattern.  Within a tile the reduction is slab-blocked: K is
-  processed in (bm, k_slab, bn) sublane slabs with one select/multiply/
-  reduce per slab instead of ``bk`` rank-1 steps (see ``_accumulate_tile``).
+  processed in (bm, k_slab, bn) slabs with one select/multiply/reduce per
+  slab instead of ``bk`` rank-1 steps, unrolled at trace time so every
+  slice offset is static (see ``_accumulate_tile``).
 * The LUT path (arbitrary 8-bit circuits, EvoApprox compatibility) keeps the
   64 Ki-entry table resident in VMEM (256 KiB as int32) and gathers per
   element; on real TPUs a VMEM gather lowers slowly, so the closed-form path
-  is the production path (see DESIGN.md).  Both validate in interpret mode.
+  is the production path (see DESIGN.md).
 
-Validated in ``interpret=True`` mode against ``ref.py`` (this container has
-no TPU); block specs and layouts are written for a real v5e target.
+The kernels are tested bit-exact against ``ref.py`` in interpret mode on
+the CPU, and compiled for a described v5e chip in tests/test_tpu_compile.py.
+``interpret=None`` (the default) interprets only where the default backend
+is the CPU (``default_interpret``).
 """
 from __future__ import annotations
 
@@ -38,10 +41,17 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.multipliers import AxMult
 from repro.core.swapper import SwapConfig, swap_mask_dyn
 
-__all__ = ["ax_matmul_pallas", "ax_matmul_grid_pallas", "HIST_WIDTH"]
+__all__ = ["ax_matmul_pallas", "ax_matmul_grid_pallas", "HIST_WIDTH",
+           "default_interpret"]
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+
+def default_interpret(interpret: Optional[bool] = None) -> bool:
+    """Whether a Pallas call runs in the interpreter: an explicit flag wins,
+    otherwise only where the default backend is the CPU (no Mosaic there).
+    On a TPU the kernels always compile."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() == "cpu"
 
 
 def _swap_select(a, b, swap: Optional[SwapConfig]):
@@ -102,12 +112,13 @@ def _accumulate_tile(a_ref, b_ref, o_ref, select, mult: AxMult, bk: int,
 
     The K reduction is slab-blocked sublane vectorization: instead of ``bk``
     rank-1 VPU steps (one (bm, 1) x (1, bn) broadcast multiply per k), each
-    loop iteration materializes a (bm, ks, bn) slab — ks sublanes of A
-    against ks rows of B — and performs ONE select/multiply/reduce over the
-    slab, cutting the loop trip count (and per-step select/multiply dispatch
-    overhead) by ks while keeping the slab temporary VMEM-resident
-    (bm * ks * bn * 4 B = 512 KiB at the default 128/8/128).  ``k_slab=1``
-    reproduces the legacy rank-1 schedule (kept as the benchmark baseline)."""
+    step materializes a (bm, ks, bn) slab — ks lanes of A against ks rows of
+    B — and performs ONE select/multiply/reduce over the slab, keeping the
+    slab temporary VMEM-resident (bm * ks * bn * 4 B = 512 KiB at the
+    default 128/8/128).  The ``bk // ks`` slab steps are unrolled in Python
+    so every slice offset is static: Mosaic lowers no ``dynamic_slice`` of a
+    loaded value, and a dynamic lane offset that is not a multiple of 128
+    is refused.  ``k_slab=1`` reproduces the rank-1 schedule."""
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -123,15 +134,14 @@ def _accumulate_tile(a_ref, b_ref, o_ref, select, mult: AxMult, bk: int,
         hist_ref[0, 0, 1, :] += _hist_row(b_blk, bits)
     ks = _pick_k_slab(bk, k_slab)
 
-    def body(s, acc):
+    acc = jnp.zeros(o_ref.shape, jnp.int32)
+    for s in range(bk // ks):
         # (bm, ks, bn) slab: ks consecutive rank-1 products, one dispatch
-        a_slab = jax.lax.dynamic_slice_in_dim(a_blk, s * ks, ks, axis=1)  # (bm, ks)
-        b_slab = jax.lax.dynamic_slice_in_dim(b_blk, s * ks, ks, axis=0)  # (ks, bn)
+        a_slab = a_blk[:, s * ks:(s + 1) * ks]                            # (bm, ks)
+        b_slab = b_blk[s * ks:(s + 1) * ks, :]                            # (ks, bn)
         aa, bb = select(a_slab[:, :, None], b_slab[None, :, :])
         prod = mult.fn(aa, bb).astype(jnp.int32)                          # (bm, ks, bn)
-        return acc + jnp.sum(prod, axis=1, dtype=jnp.int32)
-
-    acc = jax.lax.fori_loop(0, bk // ks, body, jnp.zeros(o_ref.shape, jnp.int32))
+        acc = acc + jnp.sum(prod, axis=1, dtype=jnp.int32)
     o_ref[...] += acc
 
 
@@ -177,7 +187,7 @@ def ax_matmul_pallas(
     k_slab: Optional[int] = None,
     grid_order: str = "mn",
     tile_hist: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Blocked approximate matmul; returns int32 (M, N).  ``k_slab`` sets
     the sublane depth of the vectorized K reduction (None = auto; 1 = the
@@ -216,8 +226,8 @@ def ax_matmul_pallas(
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
-        compiler_params=_CompilerParams(
+        interpret=default_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(a, b)
@@ -262,7 +272,7 @@ def ax_matmul_grid_pallas(
     k_slab: Optional[int] = None,
     grid_order: str = "mn",
     tile_hist: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Blocked approximate matmul with a per-output-tile swap-config grid
     (scalar prefetch: the grid is resident in SMEM before the body runs).
@@ -309,8 +319,8 @@ def ax_matmul_grid_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
-        compiler_params=_CompilerParams(
+        interpret=default_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(cfg_grid.astype(jnp.int32), a, b)

@@ -38,7 +38,7 @@ from repro.core.tuning import (
 )
 
 from . import schedule as SCHED
-from .ax_matmul import ax_matmul_grid_pallas, ax_matmul_pallas
+from .ax_matmul import ax_matmul_grid_pallas, ax_matmul_pallas, default_interpret
 from .schedule import KernelSchedule
 from .tuning_sweep import tuning_sweep_pallas
 
@@ -96,7 +96,7 @@ def ax_matmul(
     block_k: Optional[int] = None,
     k_slab: Optional[int] = None,
     tile_hist: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """int8 x int8 -> int32 approximate matmul with fused SWAPPER.
 
@@ -113,7 +113,8 @@ def ax_matmul(
     the per-tile statistic)."""
     sched = _sched_for("ax_matmul", "matmul", a, b, mult, schedule,
                        block_m, block_n, block_k, k_slab)
-    return _ax_matmul_jit(a, b, mult, swap, sched, tile_hist, interpret)
+    return _ax_matmul_jit(a, b, mult, swap, sched, tile_hist,
+                          default_interpret(interpret))
 
 
 ax_matmul._cache_size = _ax_matmul_jit._cache_size
@@ -149,14 +150,14 @@ def ax_matmul_dequant(
     block_m: Optional[int] = None,
     block_n: Optional[int] = None,
     block_k: Optional[int] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     out_dtype=jnp.float32,
 ) -> jax.Array:
     """Quantized approximate matmul with dequantization epilogue."""
     sched = _sched_for("ax_matmul_dequant", "matmul", a, b, mult, schedule,
                        block_m, block_n, block_k, None)
     return _ax_matmul_dequant_jit(a, b, scale_a, scale_b, mult, swap, sched,
-                                  interpret, out_dtype)
+                                  default_interpret(interpret), out_dtype)
 
 
 ax_matmul_dequant._cache_size = _ax_matmul_dequant_jit._cache_size
@@ -191,7 +192,7 @@ def ax_matmul_grid(
     block_k: Optional[int] = None,
     k_slab: Optional[int] = None,
     tile_hist: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Approximate matmul with a per-output-tile SWAPPER config grid.
 
@@ -210,7 +211,7 @@ def ax_matmul_grid(
     sched = _sched_for("ax_matmul_grid", "matmul_grid", a, b, mult, schedule,
                        block_m, block_n, block_k, k_slab)
     return _ax_matmul_grid_jit(a, b, mult, cfg_grid, sched, tile_hist,
-                               interpret)
+                               default_interpret(interpret))
 
 
 ax_matmul_grid._cache_size = _ax_matmul_grid_jit._cache_size
@@ -221,7 +222,7 @@ def component_sweep_pallas(
     tile: int = 128,
     sample_bits: Optional[int] = None,
     seed: int = 0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> ComponentResult:
     """Component-level tuning driven by the Pallas sweep kernel — a drop-in
     replacement for ``repro.core.tuning.component_sweep`` (cross-checked in

@@ -10,7 +10,7 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.models.layers import axes_for_path
@@ -18,6 +18,7 @@ from repro.models.layers import axes_for_path
 from .sharding import axis_rules, param_spec
 
 __all__ = [
+    "make_mesh",
     "make_production_mesh",
     "make_fleet_mesh",
     "param_shardings",
@@ -28,10 +29,19 @@ __all__ = [
 ]
 
 
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code places
+    activations with ``with_sharding_constraint`` (``launch.sharding.shard``),
+    which accepts only Auto axes (``jax.make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_fleet_mesh(n_replicas: Optional[int] = None):
@@ -43,7 +53,7 @@ def make_fleet_mesh(n_replicas: Optional[int] = None):
     initializes (see examples/fleet_serve.py and tests/test_fleet.py)."""
     n = n_replicas or len(jax.devices())
     assert len(jax.devices()) >= n, (n, jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",), devices=jax.devices()[:n])
 
 
 def tree_paths(tree):

@@ -66,6 +66,7 @@ import numpy as np
 from repro import obs
 from repro.configs import ARCHS, reduced
 from repro.configs.base import AxPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serve import ServeConfig, generate
 
@@ -181,11 +182,29 @@ def _drift_hook(at_step: int, scale: float):
     return hook
 
 
+def fleet_requests(args, cfg):
+    """The ``--fleet`` synthetic traffic, seeded: ``args.requests`` prompts
+    of ``prompt_len/2 .. prompt_len`` random tokens (one prompt bucket),
+    each asking for ``1 .. new_tokens`` tokens."""
+    from repro.fleet import Request
+
+    rng = np.random.default_rng(0)
+    requests = []
+    for rid in range(args.requests):
+        L = int(rng.integers(max(args.prompt_len // 2, 1), args.prompt_len + 1))
+        requests.append(Request(rid, rng.integers(0, cfg.vocab, L),
+                                max_new=int(rng.integers(1, args.new_tokens + 1))))
+    return requests
+
+
 def _run_fleet(args, cfg):
     """The mesh-native serving stack: fleet mesh + continuous batcher +
-    policy store (see module docstring)."""
+    policy store (see module docstring).  ``args`` carries the ``--fleet``
+    options of :func:`main`.  Returns ``(completions, batcher stats)``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     from repro.fleet import (BatcherConfig, ContinuousBatcher, PolicyReader,
-                             PolicyStore, Request)
+                             PolicyStore)
     from repro.launch.mesh import make_fleet_mesh
     from repro.runtime import AdaptiveConfig, AdaptiveController, SwapPolicy
 
@@ -227,7 +246,11 @@ def _run_fleet(args, cfg):
                         audit=controller.audit)
     controller.attach_slo(slo)
 
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    # one jitted init writes the weights straight into their replicated
+    # placement: no f32 intermediate per tensor, no copy per replica later
+    params = jax.jit(init_params, static_argnums=1,
+                     out_shardings=NamedSharding(mesh, P()))(
+                         jax.random.PRNGKey(0), cfg)
     bcfg = BatcherConfig(n_slots=slots,
                          prompt_buckets=(args.prompt_len,),
                          new_token_bucket=args.new_tokens,
@@ -243,12 +266,7 @@ def _run_fleet(args, cfg):
     readers = [PolicyReader(store, cfg.ax.targets, tile_rows=args.tile_rows,
                             name=f"r{i}")
                for i in range(n)]
-    rng = np.random.default_rng(0)
-    requests = []
-    for rid in range(args.requests):
-        L = int(rng.integers(max(args.prompt_len // 2, 1), args.prompt_len + 1))
-        requests.append(Request(rid, rng.integers(0, cfg.vocab, L),
-                                max_new=int(rng.integers(1, args.new_tokens + 1))))
+    requests = fleet_requests(args, cfg)
     source = None
     if args.arrival_rate > 0:
         from repro.fleet import poisson_arrivals
@@ -304,9 +322,12 @@ def _run_fleet(args, cfg):
         if controller.rollbacks:
             print(f"[chaos] rollbacks: {controller.rollbacks}")
         chaos.uninstall()
+    return done, bat.stats
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The serving driver's options (``chip_smoke.py`` parses its serving
+    arguments with this same parser)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-72b", choices=sorted(ARCHS))
     ap.add_argument("--smoke", action="store_true")
@@ -388,7 +409,12 @@ def main():
                     help="push one OTLP-JSON resourceMetrics payload at "
                          "exit: append to PATH (.jsonl) or POST to an "
                          "http(s):// collector endpoint")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    enable_compile_cache()
+    args = build_parser().parse_args()
 
     cfg = ARCHS[args.arch]
     if args.smoke:

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCHS, ParallelConfig, reduced
 from repro.configs.base import AxPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.train import (
     AdamWConfig,
@@ -34,6 +35,7 @@ from repro.train import (
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-72b", choices=sorted(ARCHS))
     ap.add_argument("--smoke", action="store_true")

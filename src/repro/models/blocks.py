@@ -77,7 +77,6 @@ def _dispatch_distributed(flat, topi, k, E, C_loc, dtype, mesh, batch_axes):
     per step on granite); giving every data shard its own capacity slice
     turns that into an all-to-all-sized reshard (EXPERIMENTS.md §Perf)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     axes = batch_axes if isinstance(batch_axes, tuple) else (batch_axes,)
 
@@ -85,11 +84,11 @@ def _dispatch_distributed(flat, topi, k, E, C_loc, dtype, mesh, batch_axes):
         buf, slots, keeps = _dispatch(fl, ti, k, E, C_loc, dtype)
         return buf, slots, keeps
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None), P(axes, None)),
         out_specs=(P(None, axes, None), P(axes, None), P(axes, None)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(flat, topi)
 
@@ -138,7 +137,6 @@ def moe_apply(p, x, cfg: ModelConfig):
         # own capacity slice; the only cross-device movement is the
         # (E,C,D) buffer resharding tokens<->experts — a true all-to-all.
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         flat_c = jax.lax.with_sharding_constraint(
             flat, NamedSharding(mesh, P(token_axes, None)))
@@ -166,12 +164,12 @@ def moe_apply(p, x, cfg: ModelConfig):
             return o
 
         tok_spec = P(token_axes, None)
-        out = shard_map(
+        out = jax.shard_map(
             local_combine, mesh=mesh,
             in_specs=(P(None, token_axes, None), tok_spec, tok_spec, tok_spec,
                       tok_spec),
             out_specs=tok_spec,
-            check_rep=False,
+            check_vma=False,
         )(y, topi_c, topv, slots, keeps)
     else:
         # reference path (single device / tests): global capacity

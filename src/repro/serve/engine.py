@@ -423,7 +423,7 @@ def _adaptive_decode_fn(cfg, par, n_steps: int, temperature: float,
 
     if mesh is not None:
         assert vectorized, "the sharded adaptive decode is the vectorized one"
-        from repro.fleet.collect import aggregate_records, shard_decode_specs, shard_map
+        from repro.fleet.collect import aggregate_records, shard_decode_specs
 
         in_specs, out_specs, axes = shard_decode_specs(cache, batch, mesh,
                                                        seeded=seeded)
@@ -517,8 +517,9 @@ def _adaptive_decode_fn(cfg, par, n_steps: int, temperature: float,
                                  bmax, dyn, jnp.zeros_like(pos0))
 
     if mesh is not None:
-        decode_scan = shard_map(decode_scan, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_rep=False)
+        decode_scan = jax.shard_map(decode_scan, mesh=mesh,
+                                    in_specs=in_specs, out_specs=out_specs,
+                                    check_vma=False)
     fn = jax.jit(decode_scan)
     _ADAPTIVE_FNS[key] = fn
     return fn
@@ -687,8 +688,7 @@ def _token_step_fn(cfg, par, temperature: float, adaptive: bool, mesh,
 
     sample = _sampler(ServeConfig(temperature=temperature))
     if mesh is not None:
-        from repro.fleet.collect import (aggregate_records, shard_map,
-                                         token_step_specs)
+        from repro.fleet.collect import aggregate_records, token_step_specs
 
         in_specs, out_specs, axes = token_step_specs(cache, batch, mesh,
                                                      seeded=seeded)
@@ -734,8 +734,8 @@ def _token_step_fn(cfg, par, temperature: float, adaptive: bool, mesh,
             return jnp.where(active, _next(logits, sub, seeds, nt), tok), cache
 
     if mesh is not None:
-        step = shard_map(step, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        step = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
     fn = jax.jit(step)
     _TOKEN_FNS[fkey] = fn
     return fn

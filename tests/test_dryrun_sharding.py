@@ -65,8 +65,9 @@ import json
 import jax
 from repro.configs.base import ParallelConfig
 from repro.launch import dryrun
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh({mesh_shape}, {mesh_axes})
+mesh = make_mesh({mesh_shape}, {mesh_axes})
 par = ParallelConfig()
 row = dryrun.run_cell("{arch}", "{shape}", False, par, verbose=False,
                       extrapolate=False, mesh=mesh)
