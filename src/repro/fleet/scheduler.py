@@ -106,21 +106,13 @@ _TTFT = _REG.histogram(
     "— EOS may free the slot's compute earlier but tokens only materialize "
     "when the fused wave returns)",
     buckets=obs.TTFT_BUCKETS)
-_QUEUE_DELAY = _REG.histogram(
-    "repro_request_queue_delay_seconds",
-    "submit -> admission (wave: popped into a wave; token: prefill "
-    "dispatched) — the arrival-pressure signal",
-    buckets=obs.TTFT_BUCKETS)
-_EOS_RETIRED = _REG.counter(
-    "repro_eos_retired_total",
-    "requests retired early by an EOS sample, before their token budget "
-    "(by mode)")
 _E2E = _REG.histogram(
     "repro_request_e2e_seconds", "submit -> request retirement (by mode)",
     buckets=obs.E2E_BUCKETS)
 _STEP_WALL = _REG.histogram(
     "repro_token_step_seconds",
-    "host wall per token-granular decode step (dispatch + host bookkeeping)",
+    "host wall per token-granular decode step: dispatch -> its tokens on "
+    "the host",
     buckets=obs.DISPATCH_BUCKETS)
 _TOKENS_PER_S = _REG.gauge(
     "repro_decode_tokens_per_second",
@@ -291,12 +283,11 @@ class ContinuousBatcher:
         if ttft is not None and observe_ttft:
             _TTFT.observe(ttft, mode=self.mode)
         _E2E.observe(e2e, mode=self.mode)
-        if queue_delay is not None:
-            _QUEUE_DELAY.observe(queue_delay, mode=self.mode)
         if self.slo is not None:
-            if ttft is not None:
-                self.slo.observe_latency("ttft", ttft)
-            self.slo.observe_latency("e2e", e2e)
+            with obs.span("slo_observe", cat="runtime", rid=req.rid):
+                if ttft is not None:
+                    self.slo.observe_latency("ttft", ttft)
+                self.slo.observe_latency("e2e", e2e)
         self.request_log.append(dict(
             rid=req.rid, bucket=self.bucket_of(len(req.tokens)),
             prompt_len=len(req.tokens), max_new=req.max_new,
@@ -523,7 +514,6 @@ class ContinuousBatcher:
                     toks = toks[:int(hits[0]) + 1]   # (EOS kept as last token)
                     finish = "eos"
                     self.stats["eos_retired"] += 1
-                    _EOS_RETIRED.inc(1, mode=self.mode)
             done.append(Completion(req.rid, toks, self.wave,
                                    len(req.tokens), bucket,
                                    corr=self._corr.pop(req.rid, None),
@@ -579,7 +569,8 @@ class ContinuousBatcher:
         if req is None:
             return None, expired
         if self.adaptive is not None and hasattr(self.adaptive, "poll"):
-            self.adaptive.poll()
+            with obs.span("policy_poll", cat="runtime", rid=req.rid):
+                self.adaptive.poll()
         L = len(req.tokens)
         bucket = self.bucket_of(L)
         padded = self._pad(req.tokens, bucket)
@@ -633,7 +624,6 @@ class ContinuousBatcher:
         if state[slot]["remaining"] == 0 or eos_hit:
             if eos_hit:
                 self.stats["eos_retired"] += 1
-                _EOS_RETIRED.inc(1, mode=self.mode)
             done.extend(self._retire(
                 slot, state, finish="eos" if eos_hit else "length"))
         elif splice:
@@ -695,7 +685,8 @@ class ContinuousBatcher:
         seeds = np.zeros(B, np.int32)        # per-slot request seeds
         done: List[Completion] = []
         k_obs = max(1, int(bc.observe_every))
-        pending = None
+        pending = pending_step = None        # telemetry the controller has
+                                             # yet to observe, and its step
         eos = bc.eos_id
         seeded = bc.temperature > 0          # per-request RNG streams
 
@@ -703,11 +694,18 @@ class ContinuousBatcher:
         tokens_at_start = self.stats["real_tokens"]
         steps_this_drain = 0
 
+        # phase spans carry the step they follow: the boundary after step
+        # k (decode_steps - 1 once it has run) holds its retire sweep,
+        # arrivals and admissions, and the next step's preparation
+        def boundary():
+            return self.stats["decode_steps"] - 1
+
         def poll_arrivals():
             if source is None:
                 return
-            for r in source.poll(time.perf_counter() - t_drain):
-                self.submit(r)               # may shed (bounded queue)
+            with obs.span("poll_arrivals", cat="scheduler", step=boundary()):
+                for r in source.poll(time.perf_counter() - t_drain):
+                    self.submit(r)           # may shed (bounded queue)
 
         def fill_slots():
             # dispatch admissions into every empty slot; sync mode splices
@@ -716,19 +714,20 @@ class ContinuousBatcher:
             # A sync admission that retires in place frees the slot again,
             # hence the inner loop.
             nonlocal cache
-            for s in range(B):
-                while state[s] is None and pending_admits[s] is None:
-                    pend, expired = self._admit_dispatch(s, key)
-                    done.extend(expired)
-                    if pend is None:
-                        break
-                    if bc.async_admission:
-                        pending_admits[s] = pend
-                    else:
-                        cache, d = self._admit_complete(
-                            pend, state, pos, tok, nt, seeds, cache,
-                            splice=steps_this_drain > 0)
-                        done.extend(d)
+            with obs.span("fill_slots", cat="scheduler", step=boundary()):
+                for s in range(B):
+                    while state[s] is None and pending_admits[s] is None:
+                        pend, expired = self._admit_dispatch(s, key)
+                        done.extend(expired)
+                        if pend is None:
+                            break
+                        if bc.async_admission:
+                            pending_admits[s] = pend
+                        else:
+                            cache, d = self._admit_complete(
+                                pend, state, pos, tok, nt, seeds, cache,
+                                splice=steps_this_drain > 0)
+                            done.extend(d)
 
         def complete_admits():
             # async admissions splice at the step boundary: their prefills
@@ -773,22 +772,21 @@ class ContinuousBatcher:
                     fill_slots()
                     continue
                 break
-            faults = chaos.fire("sched.step",
-                                step=self.stats["decode_steps"],
-                                mode=self.mode)
-            if any(f.kind == "crash_replica" for f in faults):
-                raise chaos.InjectedFault("sched.step: replica killed")
-            chaos.maybe_stall(faults, default=0.05)
-            # the corr ids live in THIS step — captured before the retire/
-            # splice sweep below, so telemetry produced by the step is
-            # charged to exactly the requests that were decoding in it
-            live_corrs = [self._corr[st["req"].rid]
-                          for st in state if st is not None]
-            key, sub = jax.random.split(key)
-            gate = (self.stats["decode_steps"] % k_obs == 0)
+            step = self.stats["decode_steps"]
+            with obs.span("step_prepare", cat="scheduler", step=step):
+                faults = chaos.fire("sched.step", step=step, mode=self.mode)
+                if any(f.kind == "crash_replica" for f in faults):
+                    raise chaos.InjectedFault("sched.step: replica killed")
+                chaos.maybe_stall(faults, default=0.05)
+                # the corr ids live in THIS step — captured before the
+                # retire/splice sweep below, so telemetry produced by the
+                # step is charged to exactly the requests decoding in it
+                live_corrs = [self._corr[st["req"].rid]
+                              for st in state if st is not None]
+                key, sub = jax.random.split(key)
+                gate = (step % k_obs == 0)
             t_step = time.perf_counter()
-            with obs.span("token_step", cat="scheduler",
-                          step=self.stats["decode_steps"],
+            with obs.span("token_step", cat="scheduler", step=step,
                           active=int(active_np.sum())):
                 out = token_step(
                     self.params, cache, jnp.asarray(tok), sub,
@@ -799,18 +797,19 @@ class ContinuousBatcher:
                     seeds=jnp.asarray(seeds) if seeded else None,
                     nt=jnp.asarray(nt) if seeded else None)
             step_wall = time.perf_counter() - t_step
-            _STEP_WALL.observe(step_wall)
             if self.watchdog.observe(step_wall):
                 self.stats["stragglers"] += 1
                 _STRAGGLERS.inc(1, mode=self.mode)
-                obs.instant("straggler", cat="scheduler",
-                            step=self.stats["decode_steps"], wall=step_wall)
+                obs.instant("straggler", cat="scheduler", step=step,
+                            wall=step_wall)
             if warmup_installs is None:
                 warmup_installs = obs.retrace_total("token_step")
             if self.adaptive is not None:
                 tok_d, cache, telem = out
                 if pending is not None:      # one-step-stale observe keeps
-                    self.adaptive.observe(pending)
+                    with obs.span("controller_observe", cat="runtime",
+                                  step=pending_step):
+                        self.adaptive.observe(pending)
                     pending = None           # the dispatch pipeline warm
                 if gate:
                     # host transfer NOW (the tok sync below drains the same
@@ -818,12 +817,18 @@ class ContinuousBatcher:
                     # charge this step's live corr set before any of them
                     # retires in the sweep below; the controller still
                     # observes one step stale, exactly as before
-                    host_telem = jax.device_get(telem)
-                    self.qor.observe_step(host_telem, live_corrs)
-                    pending = host_telem
+                    with obs.span("telemetry_read", cat="scheduler",
+                                  step=step):
+                        host_telem = jax.device_get(telem)
+                    pending, pending_step = host_telem, step
             else:
                 tok_d, cache = out
-            tok = np.array(tok_d)        # writable copy (splices update rows)
+            with obs.span("token_read", cat="scheduler", step=step):
+                tok = np.array(tok_d)    # writable copy (splices update rows)
+            _STEP_WALL.observe(time.perf_counter() - t_step)
+            if self.adaptive is not None and gate:
+                with obs.span("qor_observe", cat="runtime", step=step):
+                    self.qor.observe_step(host_telem, live_corrs)
             pos = pos + active_np
             nt = nt + active_np
             n_active = int(active_np.sum())
@@ -831,28 +836,31 @@ class ContinuousBatcher:
             self.stats["filler_tokens"] += B - n_active
             self.stats["decode_steps"] += 1
             steps_this_drain += 1
-            for s in range(B):               # retire at the step boundary
-                st = state[s]
-                if st is None:
-                    continue
-                st["toks"].append(int(tok[s]))
-                st["remaining"] -= 1
-                eos_hit = eos is not None and int(tok[s]) == eos
-                timed_out = (st["remaining"] > 0 and not eos_hit
-                             and self._deadline_passed(st["req"]))
-                if st["remaining"] == 0 or eos_hit or timed_out:
-                    if eos_hit:
-                        self.stats["eos_retired"] += 1
-                        _EOS_RETIRED.inc(1, mode=self.mode)
-                    finish = ("eos" if eos_hit else
-                              ("timeout" if timed_out else "length"))
-                    done.extend(self._retire(
-                        s, state, status="timeout" if timed_out else "ok",
-                        finish=finish))
+            with obs.span("retire_sweep", cat="scheduler", step=step):
+                for s in range(B):           # retire at the step boundary
+                    st = state[s]
+                    if st is None:
+                        continue
+                    st["toks"].append(int(tok[s]))
+                    st["remaining"] -= 1
+                    eos_hit = eos is not None and int(tok[s]) == eos
+                    timed_out = (st["remaining"] > 0 and not eos_hit
+                                 and self._deadline_passed(st["req"]))
+                    if st["remaining"] == 0 or eos_hit or timed_out:
+                        if eos_hit:
+                            self.stats["eos_retired"] += 1
+                        finish = ("eos" if eos_hit else
+                                  ("timeout" if timed_out else "length"))
+                        done.extend(self._retire(
+                            s, state,
+                            status="timeout" if timed_out else "ok",
+                            finish=finish))
             poll_arrivals()
             fill_slots()                     # splice/dispatch replacements
         if pending is not None and self.adaptive is not None:
-            self.adaptive.observe(pending)
+            with obs.span("controller_observe", cat="runtime",
+                          step=pending_step):
+                self.adaptive.observe(pending)
         post = (0 if warmup_installs is None
                 else int(obs.retrace_total("token_step") - warmup_installs))
         self.stats["decode_retraces_post_warmup"] = post

@@ -57,25 +57,6 @@ __all__ = [
     "DEFAULT_OPS",
 ]
 
-_REG = obs.default_registry()
-_TUNER_WALL = _REG.gauge(
-    "repro_sched_tuner_wall_s", "wall seconds of the last autotune sweep")
-_TUNER_CANDIDATES = _REG.counter(
-    "repro_sched_tuner_candidates_total", "candidate schedules measured")
-_TUNER_SIGNATURES = _REG.counter(
-    "repro_sched_tuner_signatures_total", "dispatch signatures tuned")
-_STORE_PUBLISHED = _REG.gauge(
-    "repro_sched_store_published_version", "last published schedule-table version")
-_STORE_PUBLISHES = _REG.counter(
-    "repro_sched_store_publishes_total", "schedule-table publishes")
-_READER_POLLS = _REG.counter(
-    "repro_sched_reader_polls_total",
-    "schedule reader polls by path (heartbeat = one-stat fast path)")
-_READER_ADOPTIONS = _REG.counter(
-    "repro_sched_reader_adoptions_total", "schedule-table adoptions by readers")
-_READ_ERRORS = _REG.counter(
-    "repro_sched_store_read_errors_total", "degraded schedule-table reads")
-
 _READ_ERRS = (OSError, ValueError, KeyError, TypeError, AssertionError)
 
 # ops tuned per backend by default (the quant layer's mxu forms have their
@@ -164,7 +145,6 @@ def sweep(fns: Sequence[Callable[[], object]], reps: int = 5,
             t0 = time.perf_counter()
             f()
             walls[i] = min(walls[i], (time.perf_counter() - t0) * 1e6)
-    _TUNER_CANDIDATES.inc(len(fns))
     return min(range(len(fns)), key=lambda i: walls[i]), walls
 
 
@@ -226,10 +206,7 @@ def tune_signature(M: int, K: int, N: int, backend: str, mult_name: str,
     cands = candidate_schedules(backend, M, K, N, op=op, quick=quick)
     a, b = _operands(M, K, N, seed=seed)
     fns = [_dispatch_fn(op, a, b, mult_name, s) for s in cands]
-    t0 = time.perf_counter()
     best, walls = sweep(fns, reps=(3 if quick else 8) if reps is None else reps)
-    _TUNER_WALL.set(time.perf_counter() - t0)
-    _TUNER_SIGNATURES.inc(1)
     report = {
         "sig": sig_key(M, K, N, backend, mult_name, op),
         "winner": cands[best].short(),
@@ -341,8 +318,7 @@ class ScheduleStore:
         for v in reversed(self.versions()):
             try:
                 return v, self.load(v)
-            except _READ_ERRS as e:
-                _READ_ERRORS.inc(1, error=type(e).__name__)
+            except _READ_ERRS:
                 continue
         return None
 
@@ -401,8 +377,6 @@ class ScheduleStore:
         self._touch_heartbeat(version)
         self._write_atomic(_CURRENT, str(version))
         self._last_published = version
-        _STORE_PUBLISHED.set(version)
-        _STORE_PUBLISHES.inc(1)
         audit = obs.audit_for_store(self)
         if audit is not None:
             audit.append("schedule_publish", store_version=version,
@@ -442,9 +416,7 @@ class ScheduleReader:
         """Adopt the current table if newer; True when it changed."""
         hb = self.store.heartbeat_ns()
         if hb is not None and hb == self._hb_seen:
-            _READER_POLLS.inc(1, path="heartbeat")
             return False
-        _READER_POLLS.inc(1, path="full")
         v = self.store.current_version()
         caught_up = hb is not None and v is not None and v >= hb
         if v is None or v == self.version:
@@ -457,7 +429,6 @@ class ScheduleReader:
         self._hb_seen = hb if caught_up else None
         if self.install:
             install_table(self.table)
-        _READER_ADOPTIONS.inc(1, replica=self.name)
         return True
 
     def _load_degrading(self, v: Optional[int]):
@@ -466,8 +437,7 @@ class ScheduleReader:
                 break
             try:
                 return v, self.store.load(v)
-            except _READ_ERRS as e:
-                _READ_ERRORS.inc(1, error=type(e).__name__)
+            except _READ_ERRS:
                 v = self.store.current_version()
         return self.store.load_newest_loadable()
 

@@ -32,7 +32,6 @@ import json
 import threading
 from typing import Dict, Optional, Tuple
 
-from repro import obs
 
 __all__ = [
     "KernelSchedule",
@@ -49,16 +48,6 @@ __all__ = [
 GRID_ORDERS = ("mn", "nm")
 LIMB_MODES = ("stacked", "split")
 BACKENDS = ("kernel", "mxu", "emul")
-
-_REG = obs.default_registry()
-_LOOKUPS = _REG.counter(
-    "repro_sched_lookups_total",
-    "KernelSchedule resolutions by result (hit = installed-table entry, "
-    "miss = zero-recompile default fallback, explicit = caller-supplied)")
-_TABLE_ENTRIES = _REG.gauge(
-    "repro_sched_table_entries",
-    "entries in the process-installed schedule table (0 = defaults only)")
-
 
 @dataclasses.dataclass(frozen=True)
 class KernelSchedule:
@@ -236,7 +225,6 @@ def install_table(table: Optional[ScheduleTable]) -> Optional[ScheduleTable]:
     global _ACTIVE
     with _LOCK:
         prev, _ACTIVE = _ACTIVE, table
-    _TABLE_ENTRIES.set(0 if table is None else len(table))
     return prev
 
 
@@ -254,15 +242,12 @@ def resolve(M: int, K: int, N: int, backend: str, mult_name: str,
     """The one resolution order every dispatch site uses:
     explicit schedule > installed-table entry > backend defaults."""
     if override is not None:
-        _LOOKUPS.inc(1, result="explicit")
         return override
     table = _ACTIVE
     if table is not None:
         hit = table.lookup(M, K, N, backend, mult_name, op)
         if hit is not None:
-            _LOOKUPS.inc(1, result="hit")
             return hit
-    _LOOKUPS.inc(1, result="miss")
     return default_schedule(backend)
 
 

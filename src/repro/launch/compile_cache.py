@@ -25,7 +25,16 @@ def enable_compile_cache() -> Optional[str]:
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
     this sets no other directory.  Otherwise the cache lives in
     :data:`CACHE_DIR`.  Programs that compile in under a second are not
-    written (JAX's ``jax_persistent_cache_min_compile_time_secs``)."""
+    written (JAX's ``jax_persistent_cache_min_compile_time_secs``).
+
+    The key covers each program's named scopes: by default JAX strips
+    debug information, scopes included, before it hashes a program, so an
+    executable compiled before a scope was added or moved would be loaded
+    in its place and a profile could not attribute the ops to it.  Source
+    frames stay out of the locations the key hashes, so the key does not
+    depend on where the checkout lies."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -116,10 +116,14 @@ def dense(x, p, ax: Optional[AxPolicy] = None, target: str = ""):
 
         scope = active_scope()
         dyn = scope.triple_for(target) if scope is not None else None
-        if dyn is not None:
-            y = ax_dense_dyn(x, w.astype(x.dtype), ax, dyn, scope=scope, target=target)
-        else:
-            y = ax_dense(x, w.astype(x.dtype), ax)
+        # HLO metadata only: traces and profiles name the approximate
+        # path's ops (quantize, limbs, int8 dot, dequantize) by target
+        with jax.named_scope(f"ax.{target}"):
+            if dyn is not None:
+                y = ax_dense_dyn(x, w.astype(x.dtype), ax, dyn, scope=scope,
+                                 target=target)
+            else:
+                y = ax_dense(x, w.astype(x.dtype), ax)
     else:
         y = x @ w.astype(x.dtype)
     if "b" in p:

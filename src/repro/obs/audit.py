@@ -25,6 +25,8 @@ import os
 import time
 from typing import List, Optional
 
+from .trace import span
+
 __all__ = ["AUDIT_FILENAME", "AuditLog", "audit_for_store", "grid_digest"]
 
 AUDIT_FILENAME = "audit.jsonl"
@@ -61,7 +63,8 @@ class AuditLog:
         ev = dict(seq=self._seq, kind=kind, unix_time=time.time(), **fields)
         self._seq += 1
         line = json.dumps(ev, sort_keys=True, default=_jsonable)
-        with open(self.path, "a") as f:
+        with span("audit_append", cat="runtime", kind=kind), \
+                open(self.path, "a") as f:
             # a crash mid-append can leave a torn line with no terminator;
             # start clean so the new event is not glued onto the wreckage
             if f.tell() and not self._ends_with_newline():

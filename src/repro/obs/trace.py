@@ -5,14 +5,18 @@ A :class:`TraceRecorder` collects events in the `Chrome Trace Event format
 (load the saved JSON in ``chrome://tracing`` / Perfetto): an
 admission -> prefill -> splice -> decode -> retire request lifetime renders
 as one visually inspectable timeline.  Recording is **opt-in and host-side
-only**: with no recorder installed every hook is a dict lookup + early
+only**: with no recorder installed every hook is a global read + early
 return, and nothing here ever enters a traced computation — instrumented
 paths stay bit-identical (tested).
 
 Surface:
 
 * ``with span("prefill", rid=3):`` — a complete ("X") event timing the
-  block; nested spans nest visually via the shared thread track.
+  block.  Each carries an ``id``, and ``args.parent`` holds the id of the
+  span that encloses it on its thread (None at the top).  While a recorder
+  is installed the block is also a ``jax.profiler.TraceAnnotation``, so a
+  running profiler shows the span on its host plane, on the device
+  trace's clock, next to the device ops.
 * ``instant("splice", slot=2)`` — a zero-duration marker ("i").
 * ``async_begin("request", 7)`` / ``async_end("request", 7)`` — an async
   ("b"/"e") pair spanning a request's whole queue->retire lifetime across
@@ -25,6 +29,7 @@ Surface:
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
 import threading
@@ -52,12 +57,14 @@ class TraceRecorder:
         self._events = []
         self._lock = threading.Lock()
         self._t0 = time.perf_counter()
+        self._ids = itertools.count(1)
+        self._local = threading.local()      # per-thread stack of open spans
 
     # -- clock ---------------------------------------------------------
     def now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
 
-    def _push(self, ev: dict) -> None:
+    def _push(self, ev) -> None:
         with self._lock:
             self._events.append(ev)
 
@@ -66,13 +73,32 @@ class TraceRecorder:
                     tid=threading.get_ident(), ts=self.now_us(),
                     args={k: _jsonable(v) for k, v in args.items()})
 
+    # -- span nesting --------------------------------------------------
+    def _open_span(self):
+        """A new span id and the id of the span open around it on this
+        thread (None at the top); the new span is open until
+        :meth:`_close_span`."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        parent = stack[-1] if stack else None
+        sid = next(self._ids)
+        stack.append(sid)
+        return sid, parent
+
+    def _close_span(self, sid: int) -> None:
+        stack = self._local.stack
+        if stack[-1] == sid:
+            stack.pop()
+        else:                                # closed out of order
+            stack.remove(sid)
+
     # -- event kinds ---------------------------------------------------
-    def complete(self, name: str, start_us: float, dur_us: float,
-                 cat: str = "serve", **args) -> None:
-        ev = self._base(name, "X", cat, args)
-        ev["ts"] = start_us
-        ev["dur"] = dur_us
-        self._push(ev)
+    def _push_span(self, name, cat, start_us, dur_us, sid, parent,
+                   args) -> None:
+        # the hot path of span(): a tuple now, the event dict in events()
+        self._push((name, cat, threading.get_ident(), start_us, dur_us, sid,
+                    parent, args))
 
     def instant(self, name: str, cat: str = "serve", **args) -> None:
         ev = self._base(name, "i", cat, args)
@@ -94,7 +120,10 @@ class TraceRecorder:
     # -- output --------------------------------------------------------
     def events(self) -> list:
         with self._lock:
-            return list(self._events)
+            evs = list(self._events)
+        pid = os.getpid()
+        return [e if isinstance(e, dict) else _span_event(pid, *e)
+                for e in evs]
 
     def to_json(self) -> str:
         return json.dumps({"traceEvents": self.events(),
@@ -107,7 +136,20 @@ class TraceRecorder:
         return path
 
 
+_PLAIN = (int, float, str, bool, type(None))
+
+
+def _span_event(pid, name, cat, tid, start_us, dur_us, sid, parent,
+                args) -> dict:
+    args = {k: _jsonable(v) for k, v in args.items()}
+    args["parent"] = parent
+    return dict(name=name, ph="X", cat=cat, pid=pid, tid=tid, ts=start_us,
+                args=args, dur=dur_us, id=sid)
+
+
 def _jsonable(v):
+    if type(v) in _PLAIN:                # the common case, without a dump
+        return v
     try:
         json.dumps(v)
         return v
@@ -130,19 +172,48 @@ def current_recorder() -> Optional[TraceRecorder]:
     return _CURRENT
 
 
-@contextlib.contextmanager
+_ANNOTATION = None       # jax.profiler.TraceAnnotation, imported on first use
+
+
+class _Span:
+    """One recorded span (see :func:`span`)."""
+
+    __slots__ = ("rec", "name", "cat", "args", "sid", "parent", "t0", "ann")
+
+    def __init__(self, rec: TraceRecorder, name: str, cat: str, args: dict):
+        self.rec, self.name, self.cat, self.args = rec, name, cat, args
+
+    def __enter__(self):
+        global _ANNOTATION
+        if _ANNOTATION is None:
+            from jax.profiler import TraceAnnotation as _ANNOTATION
+        self.sid, self.parent = self.rec._open_span()
+        self.ann = _ANNOTATION(self.name)
+        self.ann.__enter__()
+        self.t0 = self.rec.now_us()
+        return self
+
+    def __exit__(self, *exc):
+        rec = self.rec
+        t1 = rec.now_us()
+        self.ann.__exit__(*exc)
+        rec._close_span(self.sid)
+        rec._push_span(self.name, self.cat, self.t0, t1 - self.t0, self.sid,
+                       self.parent, self.args)
+        return False
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
 def span(name: str, cat: str = "serve", **args):
-    """Time a block as a complete trace event.  No-recorder case is a
-    near-free early exit — safe to leave on hot host loops."""
+    """Time a block as a complete trace event (a context manager).  The
+    no-recorder case returns a shared null context — safe to leave on hot
+    host loops."""
     rec = _CURRENT
     if rec is None:
-        yield
-        return
-    t0 = rec.now_us()
-    try:
-        yield
-    finally:
-        rec.complete(name, t0, rec.now_us() - t0, cat=cat, **args)
+        return _NO_SPAN
+    return _Span(rec, name, cat, args)
 
 
 def instant(name: str, cat: str = "serve", **args) -> None:

@@ -667,8 +667,10 @@ def ax_dense_dyn(x, w, policy: AxPolicy, dyn, scope=None, target: str = ""):
 
         mult = M.get(policy.mult_name)
         dyn_rep = dyn if dyn.ndim == 1 else dyn[0, 0]
-        scope.record(target, operand_summary(xq, wq, mult, dyn_rep,
-                                             gate=scope.gate))
+        telemetry = jax.named_scope(f"ax_telemetry.{target}")
+        with telemetry:
+            scope.record(target, operand_summary(xq, wq, mult, dyn_rep,
+                                                 gate=scope.gate))
         if scope.tile_rows > 0:
             use_kernel_hist = (getattr(scope, "kernel_hist", False)
                                and policy.backend == "kernel"
@@ -676,12 +678,14 @@ def ax_dense_dyn(x, w, policy: AxPolicy, dyn, scope=None, target: str = ""):
             if use_kernel_hist:
                 y, hist = _ax_dense_dyn_hist_core(x, w, policy, dyn,
                                                   xq, sx, wq, sw)
+                with telemetry:
+                    scope.record(tile_key(target),
+                                 tile_summary(xq, wq, mult, scope.tile_rows,
+                                              gate=scope.gate, dyn=dyn,
+                                              bits_from=hist))
+                return y
+            with telemetry:
                 scope.record(tile_key(target),
                              tile_summary(xq, wq, mult, scope.tile_rows,
-                                          gate=scope.gate, dyn=dyn,
-                                          bits_from=hist))
-                return y
-            scope.record(tile_key(target),
-                         tile_summary(xq, wq, mult, scope.tile_rows,
-                                      gate=scope.gate, dyn=dyn))
+                                          gate=scope.gate, dyn=dyn))
     return _ax_dense_dyn_core(x, w, policy, dyn, xq, sx, wq, sw)

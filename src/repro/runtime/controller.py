@@ -506,7 +506,8 @@ class AdaptiveController:
         t0 = time.perf_counter()
         _chaos().maybe_stall(_chaos().fire("controller.retune",
                                            target=target), default=0.05)
-        with obs.span("retune", cat="adapt", target=target, drift=drift):
+        with obs.span("retune", cat="adapt", target=target, drift=drift,
+                      kind="scalar"):
             a, b = self.buffers[target].operands()
             scores = np.asarray(_score_configs(
                 self.mult, jnp.asarray(a), jnp.asarray(b), self.triples,
@@ -703,7 +704,8 @@ class AdaptiveController:
         zero recompiles exactly like scalar configs (grids enter compiled
         steps as traced int32 values)."""
         t0 = time.perf_counter()
-        with obs.span("retune_tiles", cat="adapt", target=target, drift=drift):
+        with obs.span("retune", cat="adapt", target=target, drift=drift,
+                      kind="tile"):
             bufs = self.tile_buffers[target]
             gm = len(bufs)
             a_tiles = np.stack([b.operands()[0] for b in bufs])

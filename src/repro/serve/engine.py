@@ -699,9 +699,10 @@ def _token_step_fn(cfg, par, temperature: float, adaptive: bool, mesh,
         return active & (tok != eos_id) if eos_id is not None else active
 
     def _next(logits, sub, seeds, nt):
-        if seeded:
-            return slot_sample(logits[:, -1], seeds, nt, temperature)
-        return sample(logits, sub)
+        with jax.named_scope("sample"):
+            if seeded:
+                return slot_sample(logits[:, -1], seeds, nt, temperature)
+            return sample(logits, sub)
 
     if adaptive:
         from repro.runtime import ax_scope
@@ -736,7 +737,7 @@ def _token_step_fn(cfg, par, temperature: float, adaptive: bool, mesh,
     if mesh is not None:
         step = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
-    fn = jax.jit(step)
+    fn = jax.jit(step)          # traces and the benchmark name it jit_step
     _TOKEN_FNS[fkey] = fn
     return fn
 
@@ -766,8 +767,10 @@ def token_step(params, cache, tok, sub, pos, active, cfg: ModelConfig,
                                       jnp.asarray(nt, jnp.int32))
     if adaptive is None:
         return fn(params, cache, tok, sub, pos, active, *extra)
-    return fn(params, cache, tok, sub, pos, active, adaptive.dyn_tree(),
-              jnp.bool_(gate), *extra)
+    with obs.span("policy_tree", cat="runtime"):
+        dyn = adaptive.dyn_tree()
+    return fn(params, cache, tok, sub, pos, active, dyn, jnp.bool_(gate),
+              *extra)
 
 
 @functools.lru_cache(maxsize=64)
@@ -782,27 +785,31 @@ def _prefill_one_fn(cfg, par, bucket: int, max_cache_len: int,
     obs.count_retrace("prefill")        # lru miss == new compiled program
     sample = _sampler(ServeConfig(temperature=temperature))
 
+    # the program's name in traces is jit_prefill_bucket (both variants)
     if seeded:
         @jax.jit
-        def fn(params, toks, lens, seed):
+        def prefill_bucket(params, toks, lens, seed):
             logits, cache = prefill(params, {"tokens": toks}, cfg, par,
                                     max_cache_len=max_cache_len,
                                     prompt_lens=lens)
             lg = logits[jnp.arange(toks.shape[0]), lens - 1]
-            first = slot_sample(lg, seed, jnp.zeros_like(seed), temperature)
+            with jax.named_scope("sample"):
+                first = slot_sample(lg, seed, jnp.zeros_like(seed),
+                                    temperature)
             return first, cache
 
-        return fn
+        return prefill_bucket
 
     @jax.jit
-    def fn(params, toks, lens, key):
+    def prefill_bucket(params, toks, lens, key):
         logits, cache = prefill(params, {"tokens": toks}, cfg, par,
                                 max_cache_len=max_cache_len,
                                 prompt_lens=lens)
         lg = logits[jnp.arange(toks.shape[0]), lens - 1][:, None]
-        return sample(lg, key), cache
+        with jax.named_scope("sample"):
+            return sample(lg, key), cache
 
-    return fn
+    return prefill_bucket
 
 
 def prefill_one(params, tokens, length: int, cfg: ModelConfig,

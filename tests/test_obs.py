@@ -218,6 +218,82 @@ def test_span_without_recorder_is_noop():
         obs.install_recorder(prev)
 
 
+def test_span_parent_ids_nest_per_thread():
+    """Each span carries an id; args.parent is the id of the span open
+    around it on the same thread (None at the top), and request-scoped
+    args ride along unchanged."""
+    import threading
+
+    rec = obs.TraceRecorder()
+    prev = obs.install_recorder(rec)
+    try:
+        with obs.span("outer", rid=3):
+            with obs.span("mid"):
+                with obs.span("leaf", step=1):
+                    pass
+            with obs.span("sibling"):
+                pass
+            t = threading.Thread(target=lambda: obs.span("other").__enter__()
+                                 .__exit__(None, None, None))
+            t.start()
+            t.join(timeout=10)
+        with obs.span("top"):
+            pass
+    finally:
+        obs.install_recorder(prev)
+    assert not t.is_alive()
+    ev = {e["name"]: e for e in rec.events()}
+    assert len({e["id"] for e in ev.values()}) == len(ev) == 6
+    assert ev["outer"]["args"] == {"rid": 3, "parent": None}
+    assert ev["mid"]["args"]["parent"] == ev["outer"]["id"]
+    assert ev["leaf"]["args"] == {"step": 1, "parent": ev["mid"]["id"]}
+    assert ev["sibling"]["args"]["parent"] == ev["outer"]["id"]
+    assert ev["other"]["args"]["parent"] is None      # its own thread's top
+    assert ev["top"]["args"]["parent"] is None
+    # a span's event is well-formed Chrome trace JSON like the others
+    json.dumps(rec.events())
+
+
+def test_span_on_profiler_host_plane_matches_recorder(tmp_path):
+    """With a recorder installed, a span also lands on the profiler's host
+    plane (a TraceAnnotation); moved onto the trace's clock by one marker,
+    its recorder copy agrees with its xplane copy within 100 us."""
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+
+    rec = obs.TraceRecorder()
+    offset = time.perf_counter() - rec.now_us() / 1e6
+    prev = obs.install_recorder(rec)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("clock_marker"):
+            mark = time.perf_counter()
+        for _ in range(3):
+            with obs.span("phase_probe", cat="scheduler", step=0):
+                time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+        obs.install_recorder(prev)
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(
+                        (e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9))
+    shift = host["clock_marker"][0][0] - mark
+    mine = [(offset + e["ts"] / 1e6 + shift,
+             offset + (e["ts"] + e["dur"]) / 1e6 + shift)
+            for e in rec.events() if e["name"] == "phase_probe"]
+    theirs = sorted(host["phase_probe"])
+    assert len(mine) == len(theirs) == 3
+    for (a0, a1), (b0, b1) in zip(mine, theirs):
+        assert abs(a0 - b0) < 100e-6 and abs(a1 - b1) < 100e-6
+
+
 # ---------------------------------------------------------------------------
 # audit trail
 # ---------------------------------------------------------------------------

@@ -305,6 +305,113 @@ def test_wave_retire_order_and_budget_assert():
 
 
 # ---------------------------------------------------------------------------
+# named scopes and phase spans: what traces and profiles can attribute
+# ---------------------------------------------------------------------------
+
+def _op_names(hlo_text):
+    import re
+
+    return set(re.findall(r'op_name="([^"]+)"', hlo_text))
+
+
+def test_step_programs_carry_named_scopes_and_stable_names():
+    """The optimized token-step and prefill HLO name the approximate
+    projections (ax.<target>), the telemetry summaries (ax_telemetry.
+    <target>) and the sampler (sample) in their op_name metadata, and the
+    programs keep the module names profiles and the benchmark read."""
+    from repro.models import init_cache
+    from repro.serve import engine as E
+
+    cfg, params = _model()
+    ctrl = _controller(cfg)
+    B, L = 2, 24
+    cache = init_cache(cfg, B, L)
+    tok = jnp.zeros(B, jnp.int32)
+    pos = jnp.zeros(B, jnp.int32)
+    act = jnp.ones(B, bool)
+    key = jax.random.PRNGKey(0)
+    E.token_step(params, cache, tok, key, pos, act, cfg, adaptive=ctrl)
+    step_fn = E._token_step_fn(cfg, None, 0.0, True, None, cache, B)
+    step = step_fn.lower(params, cache, tok, key, pos, act, ctrl.dyn_tree(),
+                         jnp.bool_(True)).compile().as_text()
+    names = _op_names(step)
+    for scope in ("ax.mlp", "ax.attn_out", "ax_telemetry.mlp",
+                  "ax_telemetry.attn_out", "sample"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    # the telemetry summary nests inside its projection's scope
+    assert any("/ax.mlp/ax_telemetry.mlp/" in n for n in names)
+    toks = jnp.zeros((1, 16), jnp.int32)
+    lens = jnp.asarray([5], jnp.int32)
+    prefill = E._prefill_one_fn(cfg, None, 16, L, 0.0).lower(
+        params, toks, lens, key).compile().as_text()
+    seeded = E._prefill_one_fn(cfg, None, 16, L, 0.5, True).lower(
+        params, toks, lens, lens).compile().as_text()
+    for text in (prefill, seeded):
+        names = _op_names(text)
+        assert any("/sample/" in n for n in names)
+        assert any("/ax.mlp/" in n for n in names)
+    splice = E._SPLICE_FN.lower(cache, init_cache(cfg, 1, L),
+                                jnp.int32(0)).compile().as_text()
+    modules = [t.split(",", 1)[0].split()[1]
+               for t in (step, prefill, seeded, splice)]
+    assert modules == ["jit_step", "jit_prefill_bucket",
+                       "jit_prefill_bucket", "jit_splice_slot"]
+
+
+def test_token_loop_emits_each_phase_span_once_per_step():
+    """A token-granular drain under a recorder: every per-step phase span
+    appears exactly once per decode step with that step's id; the
+    boundary phases (arrivals, admissions) once per step boundary; and
+    each span's parent is the span around it."""
+    from repro import obs
+    from repro.fleet import ArrivalSource
+
+    cfg, params = _model()
+    trace = _mixed_trace(cfg, 6, seed=5)
+    bcfg = BatcherConfig(n_slots=3, prompt_buckets=(8, 16),
+                         new_token_bucket=6, token_granular=True)
+    bat = ContinuousBatcher(params, cfg, bcfg, adaptive=_controller(cfg))
+    bat.attach_slo(obs.SLOEngine(obs.default_serving_slos()))
+    rec = obs.TraceRecorder()
+    prev = obs.install_recorder(rec)
+    try:
+        bat.run_arrivals(ArrivalSource([(0.0, r) for r in trace]))
+    finally:
+        obs.install_recorder(prev)
+    n = bat.stats["decode_steps"]
+    assert n > 3 and bat.stats["splices"] > 0
+    spans = [e for e in rec.events() if e["ph"] == "X"]
+    by_id = {e["id"]: e for e in spans}
+
+    def steps_of(name):
+        return sorted(e["args"]["step"] for e in spans if e["name"] == name)
+
+    for name in ("step_prepare", "token_step", "telemetry_read",
+                 "token_read", "qor_observe", "retire_sweep",
+                 "controller_observe"):
+        assert steps_of(name) == list(range(n)), name
+    for name in ("poll_arrivals", "fill_slots"):
+        assert steps_of(name) == list(range(-1, n)), name
+
+    def parent(e):
+        p = e["args"]["parent"]
+        return None if p is None else by_id[p]["name"]
+
+    want = {"step_prepare": None, "token_step": None, "token_read": None,
+            "telemetry_read": None, "qor_observe": None,
+            "controller_observe": None, "retire_sweep": None,
+            "poll_arrivals": None, "fill_slots": None,
+            "policy_tree": "token_step", "admit_dispatch": "fill_slots",
+            "admit": "fill_slots"}
+    for e in spans:
+        if e["name"] in want:
+            assert parent(e) == want[e["name"]], e
+    slo = [parent(e) for e in spans if e["name"] == "slo_observe"]
+    assert len(slo) == len(trace)                 # one per retirement
+    assert set(slo) <= {"retire_sweep", "fill_slots"}
+
+
+# ---------------------------------------------------------------------------
 # 8-device mesh: token-granular splicing under shard_map
 # ---------------------------------------------------------------------------
 
