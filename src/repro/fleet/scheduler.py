@@ -69,6 +69,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs.base import ModelConfig, ParallelConfig
+from repro.quant.ax import prepare_params, prepared_projections
 from repro.serve import ServeConfig, generate
 from repro.serve.engine import prefill_one, splice_slot_jit, token_step
 from repro.train.fault import StragglerWatchdog
@@ -122,6 +123,11 @@ _POST_WARMUP_RETRACES = _REG.gauge(
     "token_step program installs after the first decode step of a drain — "
     "the live zero-recompile invariant (asserted 0; splices and policy "
     "updates must never retrace)")
+_AX_PREPARED = _REG.gauge(
+    "repro_ax_prepared_projections",
+    "approximated projections the batcher's steps read from weights "
+    "prepared at load (quant.ax.prepare_params); 0 = every call quantizes "
+    "its weight")
 _SHED = _REG.counter(
     "repro_requests_shed_total",
     "admissions refused because the bounded queue was full (load-shedding)")
@@ -212,7 +218,10 @@ class ContinuousBatcher:
         assert mesh is None or adaptive is not None, (
             "ContinuousBatcher: mesh= requires an adaptive controller/reader "
             "(the sharded decode program is the adaptive scan)")
-        self.params = params
+        # serving never changes the weights: quantize each approximated one
+        # and build its limbs once, so every step reads them in place
+        self.params = prepare_params(params, cfg)
+        _AX_PREPARED.set(prepared_projections(self.params))
         self.cfg = cfg
         self.bcfg = bcfg or BatcherConfig()
         # pad-mask prefill (and with it per-slot positions, budgets, and

@@ -259,6 +259,7 @@ def _run_fleet(args, cfg):
                          eos_id=args.eos_id,
                          async_admission=args.async_admission)
     bat = ContinuousBatcher(params, cfg, bcfg, adaptive=controller, mesh=mesh)
+    del params          # the batcher holds the prepared weights in their place
     bat.attach_slo(slo)
     # one logical PolicyReader per replica: they adopt the policy current at
     # spin-up and then surface the staleness metric (versions behind

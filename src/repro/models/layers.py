@@ -43,9 +43,27 @@ def ninit(key, shape, dtype, scale=None):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
 
+# the projection target ``dense`` serves under each subtree name: the names
+# the rules below read (``in/w``, ``o/w``, ...), and the map
+# ``quant.ax.prepare_params`` finds a policy's projections by
+PROJECTION_TARGETS = {"in": "mlp", "gate": "mlp", "out": "mlp",
+                      "o": "attn_out", "q": "attn_qkv", "k": "attn_qkv",
+                      "v": "attn_qkv"}
+
+
 def axes_for_path(path: str, ndim: int):
     """Logical axes for a parameter, derived from its '/'-joined path.
-    A leading 'layers' segment (scan-stacked) contributes a None axis."""
+    A leading 'layers' segment (scan-stacked) contributes a None axis.
+    The leaves of a prepared projection (``quant.ax.prepare_weight``) shard
+    like the ``w`` they replace: ``wq`` as it, ``wfg`` with its limb axis
+    unsharded, ``sw`` (1, N) along N."""
+    head, _, name = path.rpartition("/")
+    if name in ("wq", "wfg", "sw"):
+        extra = 1 if name == "wfg" else 0
+        w_axes = list(axes_for_path(f"{head}/w", ndim - extra))
+        if name == "sw":
+            w_axes[-2] = None
+        return tuple(w_axes[:-2]) + (None,) * extra + tuple(w_axes[-2:])
     parts = path.split("/")
     stacked = parts and parts[0] == "layers"
     if stacked:
@@ -108,10 +126,12 @@ def dense(x, p, ax: Optional[AxPolicy] = None, target: str = ""):
     """y = x @ w (+ b).  Routes through the SWAPPER approximate path when the
     policy covers this projection target (DESIGN.md §5).  Under an open
     adaptive-runtime scope the swap config enters as a traced triple instead
-    of a baked constant, so the controller can re-tune without recompiles."""
-    w = p["w"]
+    of a baked constant, so the controller can re-tune without recompiles.
+    A prepared ``p`` (``quant.ax.prepare_params``: serving) is read as
+    prepared; a raw ``{"w": ...}`` is quantized per call."""
     if ax is not None and target in ax.targets:
-        from repro.quant.ax import ax_dense, ax_dense_dyn
+        from repro.quant.ax import (ax_dense, ax_dense_dyn, ax_dense_prepared,
+                                    is_prepared)
         from repro.runtime.scope import active_scope
 
         scope = active_scope()
@@ -119,13 +139,16 @@ def dense(x, p, ax: Optional[AxPolicy] = None, target: str = ""):
         # HLO metadata only: traces and profiles name the approximate
         # path's ops (quantize, limbs, int8 dot, dequantize) by target
         with jax.named_scope(f"ax.{target}"):
-            if dyn is not None:
-                y = ax_dense_dyn(x, w.astype(x.dtype), ax, dyn, scope=scope,
-                                 target=target)
+            if is_prepared(p):
+                y = ax_dense_prepared(x, p, ax, dyn, scope=scope,
+                                      target=target)
+            elif dyn is not None:
+                y = ax_dense_dyn(x, p["w"].astype(x.dtype), ax, dyn,
+                                 scope=scope, target=target)
             else:
-                y = ax_dense(x, w.astype(x.dtype), ax)
+                y = ax_dense(x, p["w"].astype(x.dtype), ax)
     else:
-        y = x @ w.astype(x.dtype)
+        y = x @ p["w"].astype(x.dtype)
     if "b" in p:
         y = y + p["b"].astype(x.dtype)
     return y

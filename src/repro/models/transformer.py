@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.launch.sharding import shard
+from repro.quant.ax import take_layer
 
 from . import blocks
 from .layers import attn_apply, attn_init, make_rope, mlp_apply, mlp_init, ninit, rmsnorm
@@ -325,7 +326,7 @@ def forward(
         else:
             ys_list = []
             for n in range(st.n_periods):
-                sl = jax.tree.map(lambda t: t[n], xs)
+                sl = take_layer(xs, n)
                 (x, aux), y = scan_body((x, aux), sl)
                 ys_list.append(y)
             if mode != "train":

@@ -49,6 +49,10 @@ from repro.kernels.schedule import KernelSchedule, resolve_for
 __all__ = [
     "ax_dense",
     "ax_dense_dyn",
+    "ax_dense_prepared",
+    "prepare_params",
+    "prepared_projections",
+    "take_layer",
     "quantize_rows",
     "separable_transforms",
     "ax_matmul_int",
@@ -63,7 +67,13 @@ __all__ = [
 # separable closed forms
 # ---------------------------------------------------------------------------
 
+def _identity(x):
+    return x
+
+
 def _trunc_t(k):
+    if k == 0:
+        return _identity
     mask = jnp.int32(~((1 << k) - 1))
 
     def f(x):  # sign-magnitude low-bit truncation (matches multipliers.trunc)
@@ -92,7 +102,7 @@ def separable_transforms(mult_name: str) -> Optional[Tuple[Callable, Callable]]:
             mag = jnp.where(neg, -x, x) & inv
             return jnp.where(neg, -mag, mag)
 
-        return (lambda x: x), g
+        return _identity, g
     return None
 
 
@@ -662,30 +672,262 @@ def ax_dense_dyn(x, w, policy: AxPolicy, dyn, scope=None, target: str = ""):
     xq, sx = quantize_rows(x.astype(jnp.float32), axis=-1)
     wq, sw = quantize_rows(w.astype(jnp.float32), axis=0)
     dyn = jnp.asarray(dyn)
-    if scope is not None and scope.collect:
-        from repro.runtime.telemetry import operand_summary, tile_key, tile_summary
-
-        mult = M.get(policy.mult_name)
-        dyn_rep = dyn if dyn.ndim == 1 else dyn[0, 0]
-        telemetry = jax.named_scope(f"ax_telemetry.{target}")
-        with telemetry:
-            scope.record(target, operand_summary(xq, wq, mult, dyn_rep,
-                                                 gate=scope.gate))
-        if scope.tile_rows > 0:
-            use_kernel_hist = (getattr(scope, "kernel_hist", False)
-                               and policy.backend == "kernel"
-                               and dyn.ndim == 3)
-            if use_kernel_hist:
-                y, hist = _ax_dense_dyn_hist_core(x, w, policy, dyn,
-                                                  xq, sx, wq, sw)
-                with telemetry:
-                    scope.record(tile_key(target),
-                                 tile_summary(xq, wq, mult, scope.tile_rows,
-                                              gate=scope.gate, dyn=dyn,
-                                              bits_from=hist))
-                return y
-            with telemetry:
-                scope.record(tile_key(target),
-                             tile_summary(xq, wq, mult, scope.tile_rows,
-                                          gate=scope.gate, dyn=dyn))
+    if _kernel_hist(scope, policy, dyn):
+        y, hist = _ax_dense_dyn_hist_core(x, w, policy, dyn, xq, sx, wq, sw)
+        _record_telemetry(scope, target, policy, xq, wq, dyn, hist)
+        return y
+    _record_telemetry(scope, target, policy, xq, wq, dyn)
     return _ax_dense_dyn_core(x, w, policy, dyn, xq, sx, wq, sw)
+
+
+def _kernel_hist(scope, policy: AxPolicy, dyn) -> bool:
+    """Whether the tile statistic comes out of the matmul kernel itself."""
+    return (scope is not None and scope.collect and scope.tile_rows > 0
+            and getattr(scope, "kernel_hist", False)
+            and policy.backend == "kernel" and dyn.ndim == 3)
+
+
+def _record_telemetry(scope, target, policy: AxPolicy, xq, wq, dyn, hist=None):
+    """Emit the call's telemetry records into a collecting scope: the scalar
+    ``operand_summary`` (its live-policy error sample takes the first tile's
+    triple when ``dyn`` is a grid) and, in tile mode, the per-row-tile
+    ``tile_summary`` (bit statistic from ``hist`` when the kernel made it)."""
+    if scope is None or not scope.collect:
+        return
+    from repro.runtime.telemetry import operand_summary, tile_key, tile_summary
+
+    mult = M.get(policy.mult_name)
+    dyn_rep = dyn if dyn.ndim == 1 else dyn[0, 0]
+    with jax.named_scope(f"ax_telemetry.{target}"):
+        scope.record(target, operand_summary(xq, wq, mult, dyn_rep,
+                                             gate=scope.gate))
+        if scope.tile_rows > 0:
+            scope.record(tile_key(target),
+                         tile_summary(xq, wq, mult, scope.tile_rows,
+                                      gate=scope.gate, dyn=dyn,
+                                      bits_from=hist))
+
+
+# ---------------------------------------------------------------------------
+# prepared weights: the weight side of a projection, built once per
+# parameter set (serving), then read in place by every call
+# ---------------------------------------------------------------------------
+
+def prepare_weight(w, policy: AxPolicy, dtype) -> dict:
+    """The weight side of one approximated projection ``w`` (..., K, N),
+    leading (stacked-layer) axes kept: ``sw`` the per-column f32 scales and
+    the int8 codes, quantized from ``w`` cast to ``dtype`` (the projection
+    input's dtype) exactly as ``ax_dense`` quantizes it per call.
+
+    For the mxu backend the record holds ``wfg`` (..., 2, K, N) =
+    ``[f(wq), g(wq)]``, the limbs stacked on an axis of their own (so a
+    K-sharded projection keeps both halves on one shard), and ``wq`` only
+    where ``f`` is not the identity (else ``wq`` is ``wfg[..., 0, :, :]``).
+    The other backends build no limbs from the weight: ``wq`` alone."""
+    wq, sw = quantize_rows(w.astype(dtype).astype(jnp.float32), axis=-2)
+    if policy.backend != "mxu":
+        return {"wq": wq, "sw": sw}
+    sep = separable_transforms(policy.mult_name)
+    assert sep is not None, f"{policy.mult_name} is not separable; use backend='kernel'"
+    f, g = sep
+    wi = wq.astype(jnp.int32)
+    rec = {"wfg": jnp.stack([f(wi), g(wi)], axis=-3).astype(jnp.int8),
+           "sw": sw}
+    if f is not _identity:
+        rec["wq"] = wq
+    return rec
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def prepare_ax(ws, policy: AxPolicy, dtype):
+    """:func:`prepare_weight` of every weight in ``ws`` as one program
+    (traces name it ``jit_prepare_ax``)."""
+    return [prepare_weight(w, policy, dtype) for w in ws]
+
+
+def _projection_paths(params, targets):
+    """Paths of the ``{"w": ...}`` subtrees that ``dense`` runs under one of
+    ``targets``, found by the leaf names the sharding rules read."""
+    from repro.models.layers import PROJECTION_TARGETS
+
+    found = []
+
+    def walk(node, path):
+        for k, v in node.items():
+            if not isinstance(v, dict) or k == "experts":
+                continue          # expert FFNs are einsums, not ``dense``
+            if "w" in v and PROJECTION_TARGETS.get(k) in targets:
+                found.append(path + (k,))
+            else:
+                walk(v, path + (k,))
+
+    if isinstance(params, dict):
+        walk(params, ())
+    return found
+
+
+def prepare_params(params, cfg):
+    """A new parameter tree whose approximated projections (``dense`` under
+    a target in ``cfg.ax.targets``) hold prepared records
+    (:func:`prepare_weight`) in place of ``{"w": ...}``; biases and every
+    other leaf are the input's own arrays.  One jitted program
+    (``jit_prepare_ax``, span ``ax_prepare``).  The input is not changed;
+    for serving only — the prepared path has no gradient."""
+    ax = cfg.ax
+    paths = _projection_paths(params, ax.targets) if ax is not None else []
+    if not paths:
+        return params
+    from repro import obs
+
+    def node(tree, path):
+        for k in path:
+            tree = tree[k]
+        return tree
+
+    with obs.span("ax_prepare", cat="engine", projections=len(paths)):
+        recs = prepare_ax([node(params, p)["w"] for p in paths], ax,
+                          jnp.dtype(cfg.compute_dtype))
+        jax.block_until_ready(recs)
+    out = dict(params)
+    for path, rec in zip(paths, recs):
+        parent = out
+        for k in path[:-1]:
+            parent[k] = dict(parent[k])
+            parent = parent[k]
+        old = parent[path[-1]]
+        parent[path[-1]] = {**rec, **{k: v for k, v in old.items() if k != "w"}}
+    return out
+
+
+def is_prepared(v) -> bool:
+    """Whether ``v`` is a prepared projection record (every one has ``sw``)."""
+    return isinstance(v, dict) and "sw" in v
+
+
+def prepared_projections(params) -> int:
+    """Approximated projections a tree holds as prepared records, stacked
+    layers counted one by one."""
+    n = 0
+    for v in (params.values() if isinstance(params, dict) else ()):
+        if is_prepared(v):
+            n += int(np.prod(v["sw"].shape[:-2], dtype=np.int64))
+        elif isinstance(v, dict):
+            n += prepared_projections(v)
+    return n
+
+
+def take_layer(tree, n: int):
+    """``tree`` (stacked layers) at layer ``n``, for an unrolled layer loop.
+    A prepared record keeps its weight leaves whole, with ``layer`` = n,
+    and is sliced where it is read: XLA fuses a slice into the dot that
+    reads it, but copies a slice that enters a ``lax.cond`` — the swap-side
+    branch of the dynamic path (see :func:`_prepared_int_dyn`)."""
+    def one(v):
+        if not is_prepared(v):
+            return v[n]
+        return {"layer": n, **{k: a if k in ("wfg", "wq") else a[n]
+                               for k, a in v.items()}}
+
+    return jax.tree.map(one, tree, is_leaf=is_prepared)
+
+
+def _leaf(p, k):
+    return p[k] if "layer" not in p else p[k][p["layer"]]
+
+
+def _prepared_wq(p):
+    return _leaf(p, "wq") if "wq" in p else _leaf(p, "wfg")[0]
+
+
+def _wfg_mm(x1, x2, wfg, sched: KernelSchedule):
+    """``x1 @ wfg[0] + x2 @ wfg[1]``: one int8 dot contracting the limb axis
+    and K together (``limbs="split"``: two dots), reading ``wfg`` in place."""
+    if sched.limbs == "split":
+        return _int_mm(x1, wfg[0]) + _int_mm(x2, wfg[1])
+    x = jnp.stack([x1, x2], axis=-2)
+    return jax.lax.dot_general(
+        x, wfg, (((x.ndim - 2, x.ndim - 1), (0, 1)), ((), ())),
+        preferred_element_type=jnp.int32)
+
+
+def _prepared_int(xq, p, policy: AxPolicy):
+    """``ax_matmul_int`` (static policy) against a prepared record."""
+    wq = _prepared_wq(p)
+    swap = policy.swap
+    if policy.backend != "mxu" or (swap is not None and swap.operand == "B"):
+        return ax_matmul_int(xq, wq, policy)
+    f, g = separable_transforms(policy.mult_name)
+    ai = xq.astype(jnp.int32)
+    wfg = _leaf(p, "wfg")
+    if swap is None:
+        return _int_mm(f(ai).astype(jnp.int8), wfg[1])
+    sched = resolve_for(xq.shape, wq.shape, "mxu", policy.mult_name,
+                        "int_static")
+    s = _swap_mask(ai, swap).astype(jnp.int32)
+    return _wfg_mm((s * g(ai)).astype(jnp.int8),
+                   ((1 - s) * f(ai)).astype(jnp.int8), wfg, sched)
+
+
+def _prepared_int_dyn(xq, p, policy: AxPolicy, dyn):
+    """``ax_matmul_int_dyn`` (traced scalar triple, mxu) against a prepared
+    record.  A-side and NoSwap triples dot ``[sa*g(A) | (1-sa)*f(A)]``
+    against ``wfg`` as stored; a B-side triple masks the prepared limbs by
+    the weight's bit (the one weight-sized build, in its own branch).  The
+    weight leaves are read inside the branches, so a layer slice of them
+    (:func:`take_layer`) fuses into the dot instead of being copied."""
+    sched = resolve_for(xq.shape, p["wfg"].shape[-2:], "mxu",
+                        policy.mult_name, "int_dyn")
+    f, g = separable_transforms(policy.mult_name)
+    ai = xq.astype(jnp.int32)
+    bit, value = dyn[1], dyn[2]
+
+    def a_side(_):
+        sa = (((ai >> bit) & 1) == value).astype(jnp.int32)
+        return _wfg_mm((sa * g(ai)).astype(jnp.int8),
+                       ((1 - sa) * f(ai)).astype(jnp.int8), _leaf(p, "wfg"),
+                       sched)
+
+    def b_side(_):
+        wfg = _leaf(p, "wfg")
+        sb = ((_prepared_wq(p).astype(jnp.int32) >> bit) & 1) == value
+        y = jnp.stack([jnp.where(sb, wfg[0], 0), jnp.where(sb, 0, wfg[1])])
+        return _wfg_mm(g(ai).astype(jnp.int8), f(ai).astype(jnp.int8), y,
+                       sched)
+
+    def limb_path(_):
+        return jax.lax.cond(dyn[0] == 1, a_side, b_side, None)
+
+    if not sched.noswap_fast:
+        return limb_path(None)
+
+    def noswap_path(_):
+        return _int_mm(f(ai).astype(jnp.int8), _leaf(p, "wfg")[1])
+
+    return jax.lax.cond(value == 2, noswap_path, limb_path, None)
+
+
+def ax_dense_prepared(x, p, policy: AxPolicy, dyn=None, scope=None,
+                      target: str = ""):
+    """``ax_dense`` (``dyn`` None) or ``ax_dense_dyn`` against a prepared
+    record (:func:`prepare_weight`): only the activation is quantized, the
+    weight's codes, limbs and scales are read as prepared.  Bit-identical
+    to the raw path, telemetry records included; forward only.
+
+    The mxu backend with a scalar triple reads ``wfg`` in place; tile grids
+    and the ``kernel`` / ``emul`` backends build their operands from the
+    prepared ``wq`` as the raw path does from its per-call quantization."""
+    xq, sx = quantize_rows(x.astype(jnp.float32), axis=-1)
+    if dyn is None:
+        acc = _prepared_int(xq, p, policy)
+    else:
+        dyn = jnp.asarray(dyn)
+        wq = _prepared_wq(p)
+        hist = None
+        if _kernel_hist(scope, policy, dyn):
+            acc, hist = ax_matmul_int_dyn_hist(xq, wq, policy, dyn)
+        elif policy.backend == "mxu" and dyn.ndim == 1:
+            acc = _prepared_int_dyn(xq, p, policy, dyn)
+        else:
+            acc = ax_matmul_int_dyn(xq, wq, policy, dyn)
+        _record_telemetry(scope, target, policy, xq, wq, dyn, hist)
+    return (acc.astype(jnp.float32) * sx * p["sw"]).astype(x.dtype)

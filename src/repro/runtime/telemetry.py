@@ -141,6 +141,9 @@ def operand_summary(xq, wq, mult: AxMult, dyn, gate=None) -> dict:
     if gate is not None:
         import jax
 
+        # the weight enters the gated branch as the elements it samples: a
+        # weight that is a slice of a larger array is then not copied whole
+        wq = _flat_sample(wq, max(TELEMETRY_SAMPLE, RETUNE_SAMPLE))
         impl = lambda: operand_summary(xq, wq, mult, dyn)
         shapes = jax.eval_shape(impl)
         zeros = lambda: jax.tree.map(
@@ -215,6 +218,8 @@ def tile_summary(xq, wq, mult: AxMult, gm: int, gate=None, dyn=None,
     if gate is not None:
         import jax
 
+        # as in operand_summary: only the sampled weight enters the branch
+        wq = _flat_sample(wq, max(TILE_TELEMETRY_SAMPLE, TILE_RETUNE_SAMPLE))
         impl = lambda: tile_summary(xq, wq, mult, gm, dyn=dyn,
                                     bits_from=bits_from)
         shapes = jax.eval_shape(impl)

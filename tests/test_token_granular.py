@@ -254,6 +254,35 @@ def test_token_granular_without_adaptive():
     assert bat.stats["requests"] == 6
 
 
+def test_prepared_drain_matches_raw_engine(monkeypatch):
+    """The batcher serves from weights prepared at load
+    (quant.ax.prepare_params): the same token-granular drain with the
+    engine fed the raw weights gives the same tokens and the same
+    telemetry, and the gauge counts every approximated projection."""
+    from repro import obs
+    import repro.fleet.scheduler as SCH
+
+    cfg, params = _model()
+    trace = _mixed_trace(cfg, 6, seed=3)
+    gauge = obs.default_registry().get("repro_ax_prepared_projections")
+    c_prep = _controller(cfg)
+    prep, bat = _serve(params, cfg, True, trace, c_prep)
+    assert gauge.value() == 8            # 2 layers x (in, gate, out, o)
+    assert "wfg" in bat.params["layers"]["p0"]["mlp"]["in"]
+    monkeypatch.setattr(SCH, "prepare_params", lambda p, cfg: p)
+    c_raw = _controller(cfg)
+    raw, bat_raw = _serve(params, cfg, True, trace, c_raw)
+    assert gauge.value() == 0 and bat_raw.params is params
+    assert prep == raw and bat.stats["splices"] > 0
+    s_prep, s_raw = c_prep.telemetry.snapshot(), c_raw.telemetry.snapshot()
+    assert set(s_prep) == set(s_raw) == set(cfg.ax.targets)
+    for t in s_raw:
+        assert s_raw[t]["n_steps"] > 0
+        for f in ("mae", "wce", "ep", "n", "n_steps"):
+            assert s_prep[t][f] == s_raw[t][f], (t, f)
+        assert np.array_equal(s_prep[t]["bit_probs"], s_raw[t]["bit_probs"]), t
+
+
 def test_wave_backfills_idle_slots_from_next_fifo_bucket():
     """ISSUE satellite: idle slots admit the next FIFO requests from other
     buckets (outputs kept) instead of cycling already-admitted prompts."""
