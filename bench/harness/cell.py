@@ -15,7 +15,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
-from . import check, devtrace, reference, serving, spec, timeline, traffic
+from . import check, devtrace, serving, spec, timeline, traffic, weights
 
 
 class NoDevice(RuntimeError):
@@ -38,6 +38,7 @@ class Context:
     """What a per-layer metric reader may read (host times in seconds)."""
     cell: dict
     config: dict                 # the configuration as run
+    reference: object            # its module: the model and its work count
     mix: dict
     chips: int
     stats: dict                  # timeline.window_stats of the window
@@ -159,8 +160,9 @@ def run(name: str, seed: int, seconds: float, trace: bool, t_start: float,
              if devices[0].platform == "tpu" else None)
     compiles = serving.CompileCounter()
     mix = sp["traffic"]
-    cfg = serving.program_config(sp["config"], shrink)
-    config = serving.as_run(sp["config"], cfg)
+    model = sp["reference"]
+    cfg = serving.program_config(sp["config"], model, shrink)
+    config = serving.as_run(sp["config"], cfg, model)
     plan = traffic.Plan(mix, seed, cfg.vocab)
     stack = serving.build(cfg, mix, chips, seed)
     if fault is not None:
@@ -197,9 +199,12 @@ def run(name: str, seed: int, seconds: float, trace: bool, t_start: float,
         f"{np.percentile(lag, 50) * 1e3:.3f} ms, p99 "
         f"{np.percentile(lag, 99) * 1e3:.3f} ms")
     queued = sum(1 for r in src.due if r not in splice)
+    in_use = max(int((d.memory_stats() or {}).get("bytes_in_use", 0))
+                 for d in devices[:chips])
     log(f"requests in flight at the close: "
         f"{sum(1 for r in splice if r not in retire)}, queued {queued}; peak "
-        f"device memory {device['memory_peak_bytes'] / 1e9:.3f} GB")
+        f"device memory {device['memory_peak_bytes'] / 1e9:.3f} GB, in use at "
+        f"the close {in_use / 1e9:.3f} GB")
     log("controller: " + " | ".join(stack.log[-4:]) if stack.log
         else "controller: no re-tune")
     _log_stalls(src, stack.store_dir)
@@ -217,16 +222,24 @@ def run(name: str, seed: int, seconds: float, trace: bool, t_start: float,
     first = {c.rid: check.first_step(steps.start, splice[c.rid])
              for c in picked}
     by_step = dict(stack.policy_log.by_step)
-    params = stack.params
+    layout, placement = stack.layout, stack.placement
     in_window = dict(compiles=win.compiles, retraces=win.retraces)
     shutil.rmtree(stack.store_dir, ignore_errors=True)
+    src.bat = None                    # the source held the batcher
     del stack, win
     gc.collect()
+    log(f"device arrays alive after the stack was freed: "
+        f"{sum(a.nbytes for a in jax.live_arrays()) / 1e9:.3f} GB")
 
+    # the reference reads the weights made anew from the seed: the batcher
+    # held only their prepared form
     t_ref = time.perf_counter()
-    ref = reference.Reference(params, config)
+    ref = model.Reference(weights.make(layout, seed, placement), config)
     replayed = check.replay(ref, picked, src.prompt, first, by_step,
-                            config["approx"]["swap"], mix, control=control)
+                            config["approx"]["swap"],
+                            config["approx"]["targets"],
+                            check.padded_length(mix, model.ROWS),
+                            control=control)
     log(f"reference replay of {len(picked)} requests: "
         f"{time.perf_counter() - t_ref:.1f} s")
     stat = config["correct"]["statistic"]
@@ -257,8 +270,9 @@ def run(name: str, seed: int, seconds: float, trace: bool, t_start: float,
     out = dict(correct=bool(correct), attempted=int(stats["due"]), failed=0)
     units = {m["name"]: m["unit"] for m in sp["end_to_end"]}
     if trace:
-        ctx = Context(sp["cell"], config, mix, chips, stats, steps, times,
-                      src.prompt, dispatch, src.w0, src.w1, peaks, trace_red)
+        ctx = Context(sp["cell"], config, model, mix, chips, stats, steps,
+                      times, src.prompt, dispatch, src.w0, src.w1, peaks,
+                      trace_red)
         metrics = {}
         for entry, reader in sp["per_layer"]:
             v = reader.read(ctx)

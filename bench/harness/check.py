@@ -1,11 +1,11 @@
 """Whether what the timed path served is correct: a sample of the requests
-the window finished, each replayed through the plain reference
-(``reference.py``) over its prompt and its served tokens, row by row under
-the swap triples it was served under.  Per served token, the gap is the
-reference's best logit at that position less its logit of the served
-token (greedy serving puts the best first); the configuration names the
-statistic of the gaps that is compared (``correct.statistic``) and its
-limit."""
+the window finished, each replayed through the plain reference the
+configuration names (``bench/references/``) over its prompt and its served
+tokens, row by row under the swap triples it was served under.  Per
+served token, the gap is the reference's best logit at that position less
+its logit of the served token (greedy serving puts the best first); the
+configuration names the statistic of the gaps that is compared
+(``correct.statistic``) and its limit."""
 from __future__ import annotations
 
 import bisect
@@ -53,12 +53,11 @@ def sample(finished: list, seed: int, inside: Optional[set] = None) -> list:
     return [longest] + [rest[int(i)] for i in order[:SAMPLE - 1]]
 
 
-def padded_length(mix: dict) -> int:
+def padded_length(mix: dict, block: int) -> int:
     """One replay length for the whole cell (one compile): the longest
-    prompt plus served tokens, rounded up to the attention block."""
+    prompt plus served tokens, rounded up to the reference's row block."""
     n = max(mix["prompt_buckets"]) + mix["new_token_bucket"] - 1
-    q = reference.Q_CHUNK
-    return -(-n // q) * q
+    return -(-n // block) * block
 
 
 def rows(prompt: np.ndarray, served: np.ndarray, first_step: int,
@@ -94,20 +93,21 @@ def first_step(step_start: Dict[int, float], splice_t: float) -> int:
     return order[i]
 
 
-def replay(ref: reference.Reference, picked: list, prompts: dict,
-           first_steps: dict, by_step: dict, prefill_triple, mix: dict,
+def replay(ref, picked: list, prompts: dict, first_steps: dict,
+           by_step: dict, prefill_triple, targets, length: int,
            control: bool = False) -> dict:
     """Gap and rank of every served token of ``picked`` (and of the
-    control's first choice at the same rows, with ``control``)."""
-    length = padded_length(mix)
+    control's first choice at the same rows, with ``control``), each
+    replayed through ``ref`` (a reference module's ``Reference``) at
+    ``length`` rows."""
     keys = ("gap", "rank") + (("control_gap", "control_rank") if control else ())
     got: Dict[str, List[np.ndarray]] = {k: [] for k in keys}
     for c in picked:
         served = np.asarray(c.tokens, np.int32)
         tokens, nxt, triples, sl = rows(
             prompts[c.rid], served, first_steps[c.rid], by_step,
-            prefill_triple, ref.targets, length)
-        out = ref.gaps(tokens, nxt, triples, control=control)
+            prefill_triple, targets, length)
+        out = reference.gaps(ref, tokens, nxt, triples, control=control)
         for k in keys:
             got[k].append(out[k][sl])
     return {k: np.concatenate(v) if v else np.zeros(0) for k, v in got.items()}

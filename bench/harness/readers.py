@@ -7,7 +7,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from . import devtrace, work
+from . import devtrace
 
 
 def _steps_in(ctx):
@@ -45,7 +45,8 @@ def idle_pct(ctx) -> Optional[float]:
 def mfu_pct(ctx) -> Optional[float]:
     """100 x the least time at peak for the window's work / (window x
     chips): each prompt whose first token came in the window, and each
-    decode token at its position (``work.py``)."""
+    decode token at its position, by the work count of the configuration's
+    reference module."""
     if ctx.peaks is None:
         return None
     ops = dict(int8=0, flops=0)
@@ -56,12 +57,18 @@ def mfu_pct(ctx) -> Optional[float]:
         for i, t in enumerate(ts):
             if not ctx.w0 < t <= ctx.w1:
                 continue
-            w = (work.prefill(ctx.config, L) if i == 0
-                 else work.per_token(ctx.config, L + i - 1, True))
+            w = (ctx.reference.prefill(ctx.config, L) if i == 0
+                 else ctx.reference.per_token(ctx.config, L + i - 1, True))
             ops["int8"] += w["int8"]
             ops["flops"] += w["flops"]
     if not ops["int8"] and not ops["flops"]:
         return None
-    secs = work.seconds_at_peak(ops, ctx.peaks)
+    secs = seconds_at_peak(ops, ctx.peaks)
     return 100.0 * secs / ((ctx.w1 - ctx.w0) * ctx.chips)
 
+
+def seconds_at_peak(ops: dict, peaks: dict) -> float:
+    """The least time the chip could take: int8 ops at the int8 peak plus
+    the rest at the bf16 peak."""
+    return (ops["int8"] / peaks["int8_ops_per_s"]
+            + ops["flops"] / peaks["bf16_flops_per_s"])

@@ -44,10 +44,37 @@ class WindowClosed(Exception):
     """Raised from the arrival source when the measured window ends."""
 
 
-def program_config(config: dict, shrink: Optional[Callable] = None) -> ModelConfig:
+# keys of every configuration file that the harness reads itself (depth,
+# dtype, approximation policy, the program's architecture and the check)
+# or that describe the file; every other key belongs to the reference
+# module, which maps it to the program (``PROGRAM_KEYS``)
+COMMON_KEYS = frozenset({
+    "name", "source", "paper", "reference", "reduced", "published",
+    "assumed", "departures", "deployment", "num_hidden_layers",
+    "torch_dtype", "approx", "program", "correct"})
+
+
+def _program_value(cfg: ModelConfig, field):
+    return field(cfg) if callable(field) else getattr(cfg, field)
+
+
+def program_config(config: dict, model, shrink: Optional[Callable] = None
+                   ) -> ModelConfig:
     """The program's ModelConfig for a configuration file: the repo's
     architecture with the file's depth, dtype and approximation policy.
-    Every other number of the file must equal the program's."""
+    Every other key of the file is one the reference module ``model`` maps
+    to the program, and the program's value equals the file's; the
+    program also holds what ``model.PROGRAM_FIXED`` says its block leaves
+    out."""
+    unmapped = sorted(set(config) - COMMON_KEYS - set(model.PROGRAM_KEYS))
+    if unmapped:
+        raise ValueError(f"{config['name']}: keys that neither the harness "
+                         f"nor reference {model.NAME} maps to the program: "
+                         f"{unmapped}")
+    absent = sorted(set(model.PROGRAM_KEYS) - set(config))
+    if absent:
+        raise ValueError(f"{config['name']}: reference {model.NAME} reads "
+                         f"keys the file lacks: {absent}")
     prog = config["program"]
     base = ARCHS[prog["arch"]]
     ax = config["approx"]
@@ -60,29 +87,26 @@ def program_config(config: dict, shrink: Optional[Callable] = None) -> ModelConf
                     swap_operand="A" if op_a == 1 else "B", swap_bit=bit,
                     swap_value=value if value in (0, 1) else 0,
                     swap_enabled=value in (0, 1)))
-    want = dict(d_model=config["hidden_size"], d_ff=config["intermediate_size"],
-                n_heads=config["num_attention_heads"],
-                n_kv_heads=config["num_key_value_heads"],
-                head_dim=config["head_dim"], vocab=config["vocab_size"],
-                rope_theta=config["rope_theta"], norm_eps=config["rms_norm_eps"],
-                qkv_bias=config["attention_bias"],
-                act="silu" if config["hidden_act"] == "silu" else "gelu",
-                tie_embeddings=config["tie_word_embeddings"])
-    differ = {k: (getattr(cfg, k), v) for k, v in want.items()
-              if getattr(cfg, k) != v}
+    differ = {k: (_program_value(cfg, f), config[k])
+              for k, f in model.PROGRAM_KEYS.items()
+              if _program_value(cfg, f) != config[k]}
+    differ.update({k: (getattr(cfg, k), v)
+                   for k, v in model.PROGRAM_FIXED.items()
+                   if getattr(cfg, k) != v})
     if differ:
         raise ValueError(f"{config['name']}: the program's {prog['arch']} "
-                         f"differs from the file: {differ}")
+                         f"differs from the file and reference "
+                         f"{model.NAME} (program, file): {differ}")
     return shrink(cfg) if shrink is not None else cfg
 
 
-def as_run(config: dict, cfg: ModelConfig) -> dict:
-    """The configuration file with the sizes of ``cfg`` (a shrunken copy in
-    tests; the file itself at full size)."""
-    return dict(config, hidden_size=cfg.d_model, intermediate_size=cfg.d_ff,
-                num_attention_heads=cfg.n_heads,
-                num_key_value_heads=cfg.n_kv_heads, head_dim=cfg.head_dim_,
-                vocab_size=cfg.vocab, num_hidden_layers=cfg.n_layers)
+def as_run(config: dict, cfg: ModelConfig, model) -> dict:
+    """The configuration file with every key ``model`` maps to the program
+    read from ``cfg`` (a shrunken copy in tests; at full size the file's
+    own values)."""
+    return dict(config, num_hidden_layers=cfg.n_layers,
+                **{k: _program_value(cfg, f)
+                   for k, f in model.PROGRAM_KEYS.items()})
 
 
 class CompileCounter:
@@ -203,7 +227,8 @@ class WindowSource(ArrivalSource):
 class Stack:
     cfg: ModelConfig
     mesh: object
-    params: object
+    layout: object               # the weights' tree of ShapeDtypeStruct
+    placement: object            # and their sharding (weights.make rebuilds)
     controller: AdaptiveController
     policy_log: PolicyLog
     batcher: RecordingBatcher
@@ -214,7 +239,8 @@ class Stack:
 
 def build(cfg: ModelConfig, mix: dict, chips: int, seed: int) -> Stack:
     """Mesh, store, controller, SLO engine, weights and batcher, as
-    ``_run_fleet`` builds them."""
+    ``_run_fleet`` builds them.  As there, the batcher holds the weights
+    prepared in place of the raw tree, which is not kept."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = make_fleet_mesh(chips)
@@ -232,16 +258,19 @@ def build(cfg: ModelConfig, mix: dict, chips: int, seed: int) -> Stack:
                         audit=controller.audit)
     controller.attach_slo(slo)
     layout = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
-    params = weights.make(layout, seed, NamedSharding(mesh, P()))
+    placement = NamedSharding(mesh, P())
+    params = weights.make(layout, seed, placement)
     bcfg = BatcherConfig(n_slots=mix["slots_per_chip"] * chips,
                          prompt_buckets=tuple(mix["prompt_buckets"]),
                          new_token_bucket=mix["new_token_bucket"],
                          temperature=0.0, token_granular=True)
     plog = PolicyLog(controller)
     bat = RecordingBatcher(params, cfg, bcfg, adaptive=plog, mesh=mesh)
+    del params
     plog.stats = bat.stats
     bat.attach_slo(slo)
-    return Stack(cfg, mesh, params, controller, plog, bat, bcfg, store_dir, log)
+    return Stack(cfg, mesh, layout, placement, controller, plog, bat, bcfg,
+                 store_dir, log)
 
 
 def warm_up(stack: Stack) -> None:

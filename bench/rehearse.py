@@ -33,7 +33,7 @@ def main() -> int:
     for w in bench["workloads"]:
         sp = spec.resolve(w["name"])
         mix, chips = sp["traffic"], int(w["chips"])
-        cfg = serving.program_config(sp["config"])
+        cfg = serving.program_config(sp["config"], sp["reference"])
         mesh = make_mesh((chips,), ("data",), devices=topo.devices[:chips])
         rep = NamedSharding(mesh, P())
 

@@ -5,11 +5,15 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[2]
 
 # A configuration no cell runs, kept to exercise the paths of the
-# reference and the work count that the cells' configuration does not:
-# a plain two-matrix GELU MLP with biases, 12:1 grouped-query attention
-# (StarCoder2-15B's widths, arXiv:2402.19173, cut to 8 of 40 layers).
+# ``dense_gqa`` reference and work count that the cells' configuration does
+# not: a plain two-matrix GELU MLP with biases, 12:1 grouped-query
+# attention.  It is StarCoder2-15B's widths (arXiv:2402.19173, cut to 8 of
+# 40 layers) under the ``dense_gqa`` block -- RMSNorm, full attention, no
+# bias on the attention output -- as the program's ``starcoder2-15b`` runs
+# it; not StarCoder2's published block (LayerNorm with bias, a bias on
+# every linear, a 4,096-token sliding window).
 STARCODER2 = {
-    "name": "starcoder2-15b-8l", "hidden_size": 6144,
+    "name": "starcoder2-15b-8l", "reference": "dense_gqa", "hidden_size": 6144,
     "intermediate_size": 24576, "num_attention_heads": 48,
     "num_key_value_heads": 4, "head_dim": 128, "vocab_size": 49152,
     "num_hidden_layers": 8, "rope_theta": 100000.0, "rms_norm_eps": 1e-06,
