@@ -9,6 +9,7 @@ from .qwen2_72b import CONFIG as qwen2_72b
 from .qwen2_vl_72b import CONFIG as qwen2_vl_72b
 from .recurrentgemma_2b import CONFIG as recurrentgemma_2b
 from .starcoder2_15b import CONFIG as starcoder2_15b
+from .starcoder2_15b_published import CONFIG as starcoder2_15b_published
 from .whisper_base import CONFIG as whisper_base
 
 ARCHS = {
@@ -17,6 +18,7 @@ ARCHS = {
         qwen2_72b,
         gemma3_27b,
         starcoder2_15b,
+        starcoder2_15b_published,
         qwen15_110b,
         qwen2_vl_72b,
         deepseek_moe_16b,

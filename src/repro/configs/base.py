@@ -53,7 +53,9 @@ class ModelConfig:
     vocab: int
     head_dim: Optional[int] = None
     qkv_bias: bool = False
+    o_bias: bool = False        # a bias on the attention output projection
     rope_theta: float = 1e4
+    norm: str = "rms"           # rms (gain 1 + scale) | layer (LayerNorm, + bias)
     norm_eps: float = 1e-6
     act: str = "silu"           # silu (swiglu) | gelu (plain 2-mat mlp)
     tie_embeddings: bool = False

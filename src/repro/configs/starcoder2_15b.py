@@ -1,4 +1,8 @@
-"""starcoder2-15b [dense] — GQA, RoPE, gelu MLP, biases [arXiv:2402.19173; hf]."""
+"""starcoder2-15b [dense] — GQA, RoPE, gelu MLP, biases [arXiv:2402.19173; hf].
+
+StarCoder2-15B's widths under the dense block (RMSNorm, full attention, no
+``o`` bias), kept for the benchmark's test stub; the published block is
+``starcoder2-15b-published``."""
 from .base import ModelConfig
 
 CONFIG = ModelConfig(

@@ -11,9 +11,10 @@ adaptive and mesh-shardable) wants **fixed shapes**.  The
   attention carries a pad-mask input, so a padded prompt attends only to
   its real tokens and generates bit-identically to the same prompt served
   unpadded (bucket granularity now costs only wasted compute, never wrong
-  conditioning).  Pad-masking needs a full-attention stack — ring caches
-  and recurrent/ssm state would absorb the pad tail — so other families
-  keep the PR-3 repeat-pad wave behavior;
+  conditioning).  Pad-masking needs a stack of attention layers, full or
+  sliding-window (a ring cache keeps each prompt's last real positions) —
+  recurrent/ssm state would absorb the pad tail — so other families keep
+  the PR-3 repeat-pad wave behavior;
 * **wave mode** (the default, and the bit-exactness oracle): each wave
   admits up to ``n_slots`` requests FIFO from the oldest bucket, backfills
   remaining slots with the next FIFO requests from *other* buckets whose
@@ -69,6 +70,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs.base import ModelConfig, ParallelConfig
+from repro.models.transformer import padmask_ok
 from repro.quant.ax import prepare_params, prepared_projections
 from repro.serve import ServeConfig, generate
 from repro.serve.engine import prefill_one, splice_slot_jit, token_step
@@ -128,6 +130,11 @@ _AX_PREPARED = _REG.gauge(
     "approximated projections the batcher's steps read from weights "
     "prepared at load (quant.ax.prepare_params); 0 = every call quantizes "
     "its weight")
+_WINDOW_CLIPPED = _REG.counter(
+    "repro_window_clipped_positions_total",
+    "token mode, stacks with sliding-window layers: per decode step, the "
+    "sum over active slots of max(0, pos - window), the positions that have "
+    "left each slot's ring (host-side positions; no device sync)")
 _SHED = _REG.counter(
     "repro_requests_shed_total",
     "admissions refused because the bounded queue was full (load-shedding)")
@@ -191,7 +198,7 @@ class BatcherConfig:
     straggler_factor: float = 3.0          # per-step watchdog (train/fault)
     # EOS-triggered early retirement: a slot frees the moment it samples
     # this token (None = budget-only retirement, the pre-PR9 behavior).
-    # Requires the pad-mask (full-attention) stack, like token mode.
+    # Requires the pad-mask (attention-only) stack, like token mode.
     eos_id: Optional[int] = None
     # token mode: dispatch a freed slot's next prefill immediately but
     # splice it at the NEXT step boundary, overlapping the prefill with the
@@ -225,17 +232,20 @@ class ContinuousBatcher:
         self.cfg = cfg
         self.bcfg = bcfg or BatcherConfig()
         # pad-mask prefill (and with it per-slot positions, budgets, and
-        # idle-slot backfill) needs a full-attention stack: ring caches and
-        # recurrent/ssm state would absorb the pad tail.  Other families
-        # keep the PR-3 wave behavior (repeat-pad conditioning, idle slots
-        # cycling admitted prompts).
-        self.padmask = (cfg.family != "encdec" and all(
-            k in ("global", "dense_ffn") for k in cfg.layer_kinds()))
+        # idle-slot backfill) needs a stack of attention layers, full or
+        # sliding-window (ring caches hold each prompt's last real
+        # positions): recurrent/ssm state would absorb the pad tail.  Other
+        # families keep the PR-3 wave behavior (repeat-pad conditioning,
+        # idle slots cycling admitted prompts).
+        self.padmask = padmask_ok(cfg)
+        # the ring length of the stack's sliding-window layers (0: none)
+        self.window = (cfg.local_window if "local" in cfg.layer_kinds()
+                       else 0)
         if self.bcfg.token_granular:
             assert self.padmask, (
-                f"token-granular mode needs pad-mask prefill (full-attention "
-                f"stack); {cfg.name} has kinds "
-                f"{sorted(set(cfg.layer_kinds()))}")
+                f"token-granular mode: {cfg.name} has layer kinds "
+                f"{sorted(set(cfg.layer_kinds()))}; recurrent, ssm and "
+                f"encdec layers are not supported")
             # temperature > 0 is fine since PR 9: sampling draws from
             # per-request RNG streams (serve.engine.slot_sample), so token
             # mode stays bit-identical to the wave oracle at any temperature
@@ -806,6 +816,9 @@ class ContinuousBatcher:
                     seeds=jnp.asarray(seeds) if seeded else None,
                     nt=jnp.asarray(nt) if seeded else None)
             step_wall = time.perf_counter() - t_step
+            if self.window:
+                _WINDOW_CLIPPED.inc(int(np.maximum(
+                    pos[active_np] - self.window, 0).sum()))
             if self.watchdog.observe(step_wall):
                 self.stats["stragglers"] += 1
                 _STRAGGLERS.inc(1, mode=self.mode)

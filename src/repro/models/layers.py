@@ -23,6 +23,8 @@ __all__ = [
     "dense",
     "rmsnorm",
     "layernorm",
+    "norm_init",
+    "norm_apply",
     "make_rope",
     "apply_rope",
     "attention",
@@ -161,12 +163,34 @@ def rmsnorm(x, p, eps):
     return (out * (1.0 + p["scale"].astype(jnp.float32))).astype(x.dtype)
 
 
-def layernorm(x, p, eps):
+def _layernorm(x, gain, bias, eps):
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
     out = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (out * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)).astype(x.dtype)
+    return (out * gain + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def layernorm(x, p, eps):
+    return _layernorm(x, p["scale"].astype(jnp.float32), p["bias"], eps)
+
+
+def norm_init(cfg: ModelConfig):
+    """A decoder norm's parameters: the gain as an offset from 1
+    (``scale``), and for LayerNorm (``cfg.norm == "layer"``) a bias ``b``."""
+    p = {"scale": jnp.zeros((cfg.d_model,), jnp.float32)}
+    if cfg.norm == "layer":
+        p["b"] = jnp.zeros((cfg.d_model,), jnp.float32)
+    return p
+
+
+def norm_apply(x, p, cfg: ModelConfig):
+    """The decoder's norm: RMSNorm, or LayerNorm with bias where
+    ``cfg.norm == "layer"``; the gain is ``1 + scale`` in both."""
+    if cfg.norm != "layer":
+        return rmsnorm(x, p, cfg.norm_eps)
+    return _layernorm(x, 1.0 + p["scale"].astype(jnp.float32), p["b"],
+                      cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------
@@ -232,18 +256,99 @@ def _mask_bias(qi, kj, *, causal, window, dtype):
 COST_MODE = False
 
 
+def _kv_step(qb, qpb, scale, causal, window):
+    """One key/value chunk of the online softmax for query block ``qb``
+    (B, qc, KV, G, hd) at positions ``qpb`` (B, qc): the ``lax.scan`` body
+    over chunks ``(kb, vb, kpb)``, carrying (max, sum, accumulator)."""
+
+    def kv_step(carry, blk):
+        m_prev, l_prev, acc = carry
+        kb, vb, kpb = blk  # (B, kc, KV, hd), (B, kc)
+        s = jnp.einsum("bqkgh,bckh->bkgqc", qb, kb).astype(jnp.float32) * scale
+        bias = _mask_bias(qpb[:, None, None, :], kpb[:, None, None, :],
+                          causal=causal, window=window, dtype=jnp.float32)
+        s = s + bias  # (B,KV,G,qc,kc)
+        m_new = jnp.maximum(m_prev, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + p.sum(-1)
+        pv = jnp.einsum("bkgqc,bckh->bkgqh", p.astype(vb.dtype), vb).astype(jnp.float32)
+        acc = acc * corr[..., None] + pv
+        return (m_new, l_new, acc), None
+
+    return kv_step
+
+
+def _banded_attention(q, k, v, q_pos, k_pos, *, window, q_chunk, kv_chunk):
+    """Causal sliding-window self-attention that visits only the keys a
+    query block can see: block ``i`` scans the ``band`` keys ending at its
+    last row (``band`` = window - 1 + q_chunk, rounded up to ``kv_chunk``)
+    instead of every key.  Positions must index the sequence (row ``j`` at
+    position ``j``, pad keys pushed out), as in train and prefill."""
+    B, S, H, hd = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    scale = 1.0 / math.sqrt(hd)
+    nq = -(-S // q_chunk)
+    band = -(-(window - 1 + q_chunk) // kv_chunk) * kv_chunk
+    nk = band // kv_chunk
+    lead = band - q_chunk          # keys before a block's first row
+    tail = nq * q_chunk - S
+
+    def pad(x, front, back, fill=0):
+        return jnp.pad(x, [(0, 0), (front, back)] + [(0, 0)] * (x.ndim - 2),
+                       constant_values=fill)
+
+    qg = pad(q.reshape(B, S, KV, G, hd), 0, tail).reshape(
+        B, nq, q_chunk, KV, G, hd)
+    qp = pad(q_pos, 0, tail, fill=-(2**30)).reshape(B, nq, q_chunk)
+    kk = pad(k, lead, tail)
+    vv = pad(v, lead, tail)
+    kp = pad(k_pos, lead, tail, fill=2**30)
+
+    def q_block(args):
+        i, qb, qpb = args
+        start = i * q_chunk
+
+        def chunks(x):
+            x = jax.lax.dynamic_slice_in_dim(x, start, band, 1)
+            return x.reshape((B, nk, kv_chunk) + x.shape[2:]).swapaxes(0, 1)
+
+        m0 = jnp.full((B, KV, G, q_chunk), -1e30, jnp.float32)
+        l0 = jnp.zeros((B, KV, G, q_chunk), jnp.float32)
+        a0 = jnp.zeros((B, KV, G, q_chunk, hd), jnp.float32)
+        (m, l, acc), _ = jax.lax.scan(
+            _kv_step(qb, qpb, scale, True, window), (m0, l0, a0),
+            (chunks(kk), chunks(vv), chunks(kp)))
+        out = acc / jnp.maximum(l[..., None], 1e-30)
+        return out.transpose(0, 3, 1, 2, 4)  # (B, qc, KV, G, hd)
+
+    out = jax.lax.map(q_block, (jnp.arange(nq), qg.swapaxes(0, 1),
+                                qp.swapaxes(0, 1))).swapaxes(0, 1)
+    out = out.reshape(B, nq * q_chunk, KV, G, hd)[:, :S]
+    return out.reshape(B, S, H, hd).astype(q.dtype)
+
+
 def chunked_attention(
     q, k, v, q_pos, k_pos, *, causal=True, window=0, q_chunk=512, kv_chunk=1024,
 ):
     """Flash-style attention with O(chunk^2) memory.
 
     q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd) with H = KV * G.
-    Positions are global indices (decode offsets supported).
+    Positions are global indices (decode offsets supported).  Causal
+    sliding-window self-attention whose window is shorter than the sequence
+    visits only each query block's band of keys (:func:`_banded_attention`).
     """
     B, Sq, H, hd = q.shape
     if COST_MODE:
         q_chunk = Sq  # single q block; kv scan unrolled below
     _, Sk, KV, _ = k.shape
+    if window and causal and Sq == Sk and not COST_MODE:
+        qc = min(q_chunk, Sq, window)
+        kc = min(kv_chunk, qc)
+        if -(-(window - 1 + qc) // kc) * kc < Sk:
+            return _banded_attention(q, k, v, q_pos, k_pos, window=window,
+                                     q_chunk=qc, kv_chunk=kc)
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
     qg = q.reshape(B, Sq, KV, G, hd)
@@ -275,27 +380,11 @@ def chunked_attention(
 
     def q_block(args):
         qb, qpb = args  # (B, qc, KV, G, hd), (B, qc)
-
-        def kv_step(carry, blk):
-            m_prev, l_prev, acc = carry
-            kb, vb, kpb = blk  # (B, kc, KV, hd), (B, kc)
-            s = jnp.einsum("bqkgh,bckh->bkgqc", qb, kb).astype(jnp.float32) * scale
-            bias = _mask_bias(qpb[:, None, None, :], kpb[:, None, None, :],
-                              causal=causal, window=window, dtype=jnp.float32)
-            s = s + bias  # (B,KV,G,qc,kc)
-            m_new = jnp.maximum(m_prev, s.max(-1))
-            p = jnp.exp(s - m_new[..., None])
-            corr = jnp.exp(m_prev - m_new)
-            l_new = l_prev * corr + p.sum(-1)
-            pv = jnp.einsum("bkgqc,bckh->bkgqh", p.astype(vb.dtype), vb).astype(jnp.float32)
-            acc = acc * corr[..., None] + pv
-            return (m_new, l_new, acc), None
-
         m0 = jnp.full((B, KV, G, q_chunk), -1e30, jnp.float32)
         l0 = jnp.zeros((B, KV, G, q_chunk), jnp.float32)
         a0 = jnp.zeros((B, KV, G, q_chunk, hd), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
-            kv_step, (m0, l0, a0),
+            _kv_step(qb, qpb, scale, causal, window), (m0, l0, a0),
             (kk.swapaxes(0, 1), vv.swapaxes(0, 1), kp.swapaxes(0, 1)),
             unroll=nk if COST_MODE else 1,
         )
@@ -350,7 +439,30 @@ def attn_init(key, cfg: ModelConfig, dtype):
     if cfg.qkv_bias:
         for nm, width in (("q", H), ("k", KVH), ("v", KVH)):
             p[nm]["b"] = jnp.zeros((width,), dtype)
+    if cfg.o_bias:
+        p["o"]["b"] = jnp.zeros((cfg.d_model,), dtype)
     return p
+
+
+def _ring_fill(k, v, ring, lens, dtype):
+    """A prefill's K/V (B, S, KV, hd) as a decode-ready ring of ``ring``
+    positions: slot ``s`` holds the latest real position ``p`` with ``p %
+    ring == s``, so each row keeps the last ``ring`` of its ``lens`` real
+    positions (all ``S`` without ``lens``) and never its pad tail; a slot
+    no real position reaches is zero.  Decode at position ``p`` then writes
+    slot ``p % ring``."""
+    B, S = k.shape[:2]
+    last = (jnp.full((B,), S, jnp.int32) if lens is None
+            else jnp.asarray(lens, jnp.int32))[:, None] - 1
+    src = last - jnp.mod(last - jnp.arange(ring, dtype=jnp.int32)[None], ring)
+    idx = jnp.clip(src, 0, S - 1)[:, :, None, None]
+    keep = (src >= 0)[:, :, None, None]
+
+    def fill(x):
+        x = jnp.take_along_axis(x.astype(dtype), idx, axis=1)
+        return jnp.where(keep, x, jnp.zeros((), dtype))
+
+    return fill(k), fill(v)
 
 
 def attn_apply(
@@ -377,7 +489,7 @@ def attn_apply(
     region inert).  ``prompt_lens`` — optional ``(B,)`` int32 of real prompt
     lengths for prefill: right-pad key positions beyond a slot's length are
     pushed outside every causal window so padded prompts attend only to real
-    tokens.
+    tokens, and a local layer's ring keeps each slot's last real positions.
     """
     B, S, _ = x.shape
     hd = cfg.head_dim_
@@ -392,87 +504,78 @@ def attn_apply(
     else:
         k, v = cross_kv  # precomputed encoder K/V (whisper cross-attention)
 
-    cdtype = jnp.dtype(cfg.compute_dtype)
-    new_cache = None
-    if mode == "decode" and cross_kv is None:
-        ring = cache["k"].shape[1]
-        ci = jnp.asarray(cache_index, jnp.int32)
-        slot = (ci % ring) if window else ci
-        if ci.ndim == 0:
-            kc = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                              (0, slot, 0, 0))
-            vc = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                              (0, slot, 0, 0))
-        else:
-            # per-slot positions: each batch row writes its own cache row;
-            # masked rows are redirected out of bounds and dropped, so a
-            # retired slot's cache region stays byte-identical until a fresh
-            # request is spliced in
-            tgt = slot if write_mask is None else jnp.where(write_mask, slot, ring)
-            rows = jnp.arange(B)
-            kc = cache["k"].at[rows, tgt].set(k[:, 0].astype(cache["k"].dtype),
-                                              mode="drop")
-            vc = cache["v"].at[rows, tgt].set(v[:, 0].astype(cache["v"].dtype),
-                                              mode="drop")
-        kc = shard(kc, "batch", "kv_seq", "kv_heads", None)
-        vc = shard(vc, "batch", "kv_seq", "kv_heads", None)
-        valid = jnp.minimum(ci + 1, ring)
-        # scalar per-batch position (M-RoPE decode uses the temporal stream)
-        qp = pos[:, 0] if pos.ndim == 2 else pos[:, 0, 0]
-        out = decode_attention(
-            q, kc, vc,
-            q_pos=(jnp.full((B,), ring - 1, jnp.int32) if window else qp),
-            kv_len=jnp.broadcast_to(valid.astype(jnp.int32), (B,)),
-        )
-        new_cache = {"k": kc, "v": vc}
-    elif mode == "decode":
-        out = decode_attention(
-            q, k, v,
-            q_pos=jnp.full((B,), k.shape[1] - 1, jnp.int32),
-            kv_len=jnp.full((B,), k.shape[1], jnp.int32),
-        )
-    else:
-        qpos = pos if pos.ndim == 2 else pos[..., 0]
-        if cross_kv is not None:  # enc-dec cross attention: kv has its own axis
-            kpos = jnp.broadcast_to(
-                jnp.arange(k.shape[1], dtype=jnp.int32)[None], (B, k.shape[1])
-            )
-        else:
-            kpos = qpos
-        if prompt_lens is not None and cross_kv is None:
-            # pad-mask prefill: push right-pad key positions outside every
-            # causal window, so real queries attend only to real tokens
-            # (pad queries produce garbage rows that nothing reads — the
-            # engine samples at each slot's last *real* position)
-            idx = jnp.arange(S, dtype=jnp.int32)[None, :]
-            kpos = jnp.where(idx < prompt_lens[:, None], kpos, 2 ** 30)
-        out = chunked_attention(
-            q, k, v, qpos, kpos, causal=causal, window=window,
-            q_chunk=q_chunk, kv_chunk=kv_chunk,
-        )
-        if mode == "prefill" and cross_kv is None:
-            if window:
-                assert prompt_lens is None, (
-                    "pad-mask prefill: ring (sliding-window) caches hold the "
-                    "last `window` positions including pads; token-granular "
-                    "serving supports full-attention cache layouts only")
-                ring = min(window, max_cache_len)
-                take = min(ring, S)
-                import numpy as _np
-
-                last_pos = _np.arange(S - take, S)
-                slots = _np.mod(last_pos, ring)
-                kc = jnp.zeros((B, ring, cfg.n_kv_heads, hd), cdtype)
-                vc = jnp.zeros((B, ring, cfg.n_kv_heads, hd), cdtype)
-                kc = kc.at[:, slots].set(k[:, -take:].astype(cdtype))
-                vc = vc.at[:, slots].set(v[:, -take:].astype(cdtype))
+    # HLO metadata only: traces and profiles name the attention proper
+    # (cache write, scores, softmax, values; not the projections) by kind
+    with jax.named_scope("attn.local" if window else "attn.global"):
+        cdtype = jnp.dtype(cfg.compute_dtype)
+        new_cache = None
+        if mode == "decode" and cross_kv is None:
+            ring = cache["k"].shape[1]
+            ci = jnp.asarray(cache_index, jnp.int32)
+            slot = (ci % ring) if window else ci
+            if ci.ndim == 0:
+                kc = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
+                                                  (0, slot, 0, 0))
+                vc = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
+                                                  (0, slot, 0, 0))
             else:
-                pad = max_cache_len - S
-                kc = jnp.pad(k.astype(cdtype), ((0, 0), (0, pad), (0, 0), (0, 0)))
-                vc = jnp.pad(v.astype(cdtype), ((0, 0), (0, pad), (0, 0), (0, 0)))
+                # per-slot positions: each batch row writes its own cache row;
+                # masked rows are redirected out of bounds and dropped, so a
+                # retired slot's cache region stays byte-identical until a fresh
+                # request is spliced in
+                tgt = slot if write_mask is None else jnp.where(write_mask, slot, ring)
+                rows = jnp.arange(B)
+                kc = cache["k"].at[rows, tgt].set(k[:, 0].astype(cache["k"].dtype),
+                                                  mode="drop")
+                vc = cache["v"].at[rows, tgt].set(v[:, 0].astype(cache["v"].dtype),
+                                                  mode="drop")
             kc = shard(kc, "batch", "kv_seq", "kv_heads", None)
             vc = shard(vc, "batch", "kv_seq", "kv_heads", None)
+            valid = jnp.minimum(ci + 1, ring)
+            # scalar per-batch position (M-RoPE decode uses the temporal stream)
+            qp = pos[:, 0] if pos.ndim == 2 else pos[:, 0, 0]
+            out = decode_attention(
+                q, kc, vc,
+                q_pos=(jnp.full((B,), ring - 1, jnp.int32) if window else qp),
+                kv_len=jnp.broadcast_to(valid.astype(jnp.int32), (B,)),
+            )
             new_cache = {"k": kc, "v": vc}
+        elif mode == "decode":
+            out = decode_attention(
+                q, k, v,
+                q_pos=jnp.full((B,), k.shape[1] - 1, jnp.int32),
+                kv_len=jnp.full((B,), k.shape[1], jnp.int32),
+            )
+        else:
+            qpos = pos if pos.ndim == 2 else pos[..., 0]
+            if cross_kv is not None:  # enc-dec cross attention: kv has its own axis
+                kpos = jnp.broadcast_to(
+                    jnp.arange(k.shape[1], dtype=jnp.int32)[None], (B, k.shape[1])
+                )
+            else:
+                kpos = qpos
+            if prompt_lens is not None and cross_kv is None:
+                # pad-mask prefill: push right-pad key positions outside every
+                # causal window, so real queries attend only to real tokens
+                # (pad queries produce garbage rows that nothing reads — the
+                # engine samples at each slot's last *real* position)
+                idx = jnp.arange(S, dtype=jnp.int32)[None, :]
+                kpos = jnp.where(idx < prompt_lens[:, None], kpos, 2 ** 30)
+            out = chunked_attention(
+                q, k, v, qpos, kpos, causal=causal, window=window,
+                q_chunk=q_chunk, kv_chunk=kv_chunk,
+            )
+            if mode == "prefill" and cross_kv is None:
+                if window:
+                    kc, vc = _ring_fill(k, v, min(window, max_cache_len),
+                                        prompt_lens, cdtype)
+                else:
+                    pad = max_cache_len - S
+                    kc = jnp.pad(k.astype(cdtype), ((0, 0), (0, pad), (0, 0), (0, 0)))
+                    vc = jnp.pad(v.astype(cdtype), ((0, 0), (0, pad), (0, 0), (0, 0)))
+                kc = shard(kc, "batch", "kv_seq", "kv_heads", None)
+                vc = shard(vc, "batch", "kv_seq", "kv_heads", None)
+                new_cache = {"k": kc, "v": vc}
 
     out = out.reshape(B, S, cfg.n_heads * hd)
     return dense(out, p["o"], ax, "attn_out"), new_cache

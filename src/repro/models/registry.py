@@ -49,7 +49,8 @@ def prefill(params, batch, cfg: ModelConfig, par=None, *, max_cache_len: int,
             prompt_lens=None):
     """``prompt_lens`` — optional (B,) int32 of real prompt lengths: the
     pad-mask prefill path (right-padded prompts attend only to real tokens;
-    full-attention stacks only — see ``transformer.forward``)."""
+    attention-only stacks, full or sliding-window — see
+    ``transformer.forward``)."""
     if prompt_lens is not None:
         assert cfg.family != "encdec", "pad-mask prefill: encdec unsupported"
     logits, cache, _ = _mod(cfg).forward(

@@ -29,9 +29,10 @@ from repro.launch.sharding import shard
 from repro.quant.ax import take_layer
 
 from . import blocks
-from .layers import attn_apply, attn_init, make_rope, mlp_apply, mlp_init, ninit, rmsnorm
+from .layers import (attn_apply, attn_init, make_rope, mlp_apply, mlp_init,
+                     ninit, norm_apply, norm_init)
 
-__all__ = ["init_params", "forward", "Stack", "init_cache"]
+__all__ = ["init_params", "forward", "Stack", "init_cache", "padmask_ok"]
 
 
 # ---------------------------------------------------------------------------
@@ -40,7 +41,7 @@ __all__ = ["init_params", "forward", "Stack", "init_cache"]
 
 def _layer_init(key, cfg: ModelConfig, kind: str, dtype):
     ks = jax.random.split(key, 4)
-    p = {"ln1": {"scale": jnp.zeros((cfg.d_model,), jnp.float32)}}
+    p = {"ln1": norm_init(cfg)}
     if kind in ("global", "local", "dense_ffn"):
         p["attn"] = attn_init(ks[0], cfg, dtype)
     elif kind == "recurrent":
@@ -50,7 +51,7 @@ def _layer_init(key, cfg: ModelConfig, kind: str, dtype):
         return p  # mamba block: single residual branch
     else:
         raise ValueError(kind)
-    p["ln2"] = {"scale": jnp.zeros((cfg.d_model,), jnp.float32)}
+    p["ln2"] = norm_init(cfg)
     if cfg.family == "moe" and kind != "dense_ffn":
         p["moe"] = blocks.moe_init(ks[1], cfg, dtype)
     else:
@@ -65,7 +66,7 @@ def _layer_apply(p, x, cfg: ModelConfig, kind: str, *, pos, inv_freq, mode,
                  prompt_lens=None, write_mask=None):
     """Returns (x, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    h = norm_apply(x, p["ln1"], cfg)
     if kind in ("global", "local", "dense_ffn"):
         window = cfg.local_window if kind == "local" else 0
         a, new_cache = attn_apply(
@@ -89,13 +90,22 @@ def _layer_apply(p, x, cfg: ModelConfig, kind: str, *, pos, inv_freq, mode,
     else:
         raise ValueError(kind)
     x = x + a
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+    h = norm_apply(x, p["ln2"], cfg)
     if "moe" in p:
         m, aux = blocks.moe_apply(p["moe"], h, cfg)
     else:
         m = mlp_apply(p["mlp"], h, cfg.act, cfg.ax)
     x = shard(x + m, "batch", "seq", None)
     return x, new_cache, aux
+
+
+def padmask_ok(cfg: ModelConfig) -> bool:
+    """Whether the stack takes pad-mask prefill and per-slot decode (the
+    token-granular path): attention layers only, full or sliding-window
+    (ring caches); recurrent and ssm state, and encoder-decoder stacks,
+    would absorb the pad tail."""
+    return cfg.family != "encdec" and all(
+        k in ("global", "local", "dense_ffn") for k in cfg.layer_kinds())
 
 
 def _empty_cache(cfg: ModelConfig, kind: str, batch, max_len, dtype):
@@ -168,7 +178,7 @@ def init_params(key, cfg: ModelConfig, dtype=None):
     V = cfg.padded_vocab
     params = {
         "embed": {"w": ninit(keys[0], (V, cfg.d_model), dtype, scale=0.02)},
-        "ln_f": {"scale": jnp.zeros((cfg.d_model,), jnp.float32)},
+        "ln_f": norm_init(cfg),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = {"w": ninit(keys[1], (V, cfg.d_model), dtype, scale=0.02)}
@@ -229,18 +239,20 @@ def forward(
     ``cache_index`` — decode position: a scalar (whole-batch, the wave path)
     or an int32 ``(B,)`` vector of per-slot positions (token-granular
     serving).  ``prompt_lens`` — optional ``(B,)`` real prompt lengths for
-    pad-mask prefill (right-padded prompts attend only to real tokens);
-    requires a full-attention stack (no ring/recurrent/ssm state, which
-    would absorb the pad tail).  ``write_mask`` — optional ``(B,)`` bool
-    gating decode cache writes per slot (retired slots stay inert).
+    pad-mask prefill (right-padded prompts attend only to real tokens; a
+    sliding-window layer's ring holds each prompt's last real positions);
+    refused for recurrent and ssm layers, whose state would absorb the pad
+    tail.  ``write_mask`` — optional ``(B,)`` bool gating decode cache
+    writes per slot (retired slots stay inert).
     """
     par = par or ParallelConfig()
     dtype = jnp.dtype(cfg.compute_dtype)
     st = Stack(cfg)
     if prompt_lens is not None:
-        assert all(k in ("global", "dense_ffn") for k in cfg.layer_kinds()), (
-            f"pad-mask prefill needs a full-attention stack; "
-            f"{cfg.name} has kinds {sorted(set(cfg.layer_kinds()))}")
+        assert padmask_ok(cfg), (
+            f"pad-mask prefill: {cfg.name} has layer kinds "
+            f"{sorted(set(cfg.layer_kinds()))}; recurrent, ssm and encdec "
+            f"layers are not supported")
     x, pos = _embed_in(params, batch, cfg, dtype)
     x = shard(x, "batch", "seq", None)
     B = x.shape[0]
@@ -340,7 +352,7 @@ def forward(
         if mode != "train":
             new_cache[f"rest{i}"] = nc
 
-    x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    x = norm_apply(x, params["ln_f"], cfg)
     head_w = params["embed"]["w"] if cfg.tie_embeddings else params["lm_head"]["w"]
     logits = jnp.einsum("bsd,vd->bsv", x, head_w.astype(x.dtype))
     logits = shard(logits, "batch", None, "vocab")  # vocab-parallel loss

@@ -778,8 +778,9 @@ def _prefill_one_fn(cfg, par, bucket: int, max_cache_len: int,
                     temperature: float, seeded: bool = False):
     """Jitted single-request prefill for one prompt bucket: pad-masked
     forward, first token sampled at the last real position, cache padded to
-    the shared ``max_cache_len`` so it splices straight into any slot of
-    the token-granular decode cache.  ``seeded`` samples the first token as
+    the shared ``max_cache_len`` (a sliding-window layer's: its ring of the
+    prompt's last real positions, as ``init_cache`` sizes it) so it splices
+    straight into any slot of the token-granular decode cache.  ``seeded`` samples the first token as
     index 0 of the request's RNG stream (:func:`slot_sample`) instead of
     from the shared key."""
     obs.count_retrace("prefill")        # lru miss == new compiled program
@@ -817,7 +818,7 @@ def prefill_one(params, tokens, length: int, cfg: ModelConfig,
                 temperature: float = 0.0, key=None, seed=None):
     """Prefill ONE padded request ``tokens`` (1, bucket) with real length
     ``length``; returns ``(first_token (1,), cache)`` with the cache padded
-    to ``max_cache_len``.  Compiled once per prompt bucket (the seeded and
+    to ``max_cache_len`` (rings of the window for sliding-window layers).  Compiled once per prompt bucket (the seeded and
     shared-key samplers are distinct bucket programs).  ``seed`` selects
     per-request-stream sampling: the first token is drawn as index 0 of the
     stream keyed on ``seed``, matching :func:`generate`'s ``slot_seeds``
